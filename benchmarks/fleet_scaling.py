@@ -41,21 +41,12 @@ import numpy as np
 
 from repro.core import (
     BatchedJointSplitter,
-    CapacityForecaster,
-    FleetOrchestrator,
-    ForecastConfig,
-    InProcessAgent,
     JaxJointSplitter,
-    ReconfigurationBroadcast,
     SessionProblem,
     ShardedFleetOrchestrator,
-    Thresholds,
     Workload,
-    make_transformer_graph,
 )
 from repro.core.placement import repair_capacity, surrogate_cost
-from repro.core.profiling import CapacityProfiler
-from repro.core.splitter import coalesce_same_node
 from repro.edgesim import (
     ChaosSpec,
     FailureSpec,
@@ -64,11 +55,12 @@ from repro.edgesim import (
     MECScenarioParams,
     base_system_state,
     build_fleet_scenario,
-    build_regional_orchestrator,
-    diurnal,
     fleet_model_catalog,
+    hot_sharded_fleet,
+    saturated_fleet,
     spike_onsets,
 )
+from repro.launch.compile_cache import enable_compile_cache
 
 _BATCHES = (1, 2, 4, 8, 16, 32, 64)
 
@@ -136,48 +128,6 @@ def solver_amortization(*, reps: int = 5, max_units: int = 96) -> list[dict]:
     return rows
 
 
-def _saturated_fleet(n_sessions: int, seed: int,
-                     forecast: bool = False,
-                     cost_model=None,
-                     fixed_point: bool = True) -> FleetOrchestrator:
-    """A fleet of ``n_sessions`` live sessions on the §IV topology, loaded
-    hard enough that latency/util triggers fire every monitoring cycle.
-
-    Solver throttling is disabled and the cool-down kept below the cycle
-    spacing so every cycle exercises the full decision hot path (trigger →
-    migrate DP → re-split → hysteresis) — the degraded steady state in
-    which PR-1 burned ~80 ms/cycle at 32 sessions and PR-2 ~45 ms."""
-    from repro.core import CapacityForecaster, ForecastConfig
-
-    state = base_system_state(MECScenarioParams())
-    orch = FleetOrchestrator(
-        profiler=CapacityProfiler(base_state=state),
-        broadcast=ReconfigurationBroadcast(
-            [InProcessAgent(i) for i in range(state.num_nodes)]
-        ),
-        thresholds=Thresholds(cooldown_s=0.5),
-        solve_backoff_s=0.0,
-        # short season so the predictor goes live inside the warmup steps
-        # and the measured cycles pay the FULL forecast path (fused ring
-        # update + worst-case re-pricing + forecast-priced migrate)
-        forecaster=(CapacityForecaster(ForecastConfig(
-            horizon_steps=8, season_steps=8)) if forecast else None),
-        cost_model=cost_model,
-        use_fixed_point=fixed_point,
-    )
-    rng = np.random.default_rng(seed)
-    catalog = fleet_model_catalog()
-    for _ in range(n_sessions):
-        _, graph = catalog[int(rng.integers(len(catalog)))]
-        wl = Workload(
-            tokens_in=int(rng.integers(32, 96)),
-            tokens_out=int(rng.integers(8, 16)),
-            arrival_rate=float(rng.uniform(2.0, 5.0)),  # deliberately hot
-        )
-        orch.admit(graph, wl, source_node=int(rng.integers(0, 3)), now=0.0)
-    return orch
-
-
 def _pcts(xs, scale=1e3) -> dict[str, float]:
     return {f"p{q}": round(scale * float(np.percentile(xs, q)), 3)
             for q in (50, 90, 95)}
@@ -224,7 +174,7 @@ def monitoring_cost(*, sessions=(32, 64, 128), cycles: int = 15,
 
     rows = []
     for n in sessions:
-        orch = _saturated_fleet(n, seed)
+        orch = saturated_fleet(n, seed)
         t = _warm(orch, cold=False)
         t_res, t_eval, t_pack = [], [], []
         repair0 = repair_capacity.calls
@@ -241,7 +191,7 @@ def monitoring_cost(*, sessions=(32, 64, 128), cycles: int = 15,
 
         # A/B: identical fleet, but the resident state is dropped before
         # every cycle so each step pays the full O(fleet) repack + transfer
-        orch = _saturated_fleet(n, seed)
+        orch = saturated_fleet(n, seed)
         t = _warm(orch, cold=True)
         t_cold = []
         for c in range(cycles):
@@ -253,7 +203,7 @@ def monitoring_cost(*, sessions=(32, 64, 128), cycles: int = 15,
         # forecast-on arm: identical fleet with a live CapacityForecaster —
         # measures the fused seasonal update + worst-case re-pricing +
         # forecast-priced migrate overhead on the same cycles (v3 metric)
-        orch = _saturated_fleet(n, seed, forecast=True)
+        orch = saturated_fleet(n, seed, forecast=True)
         t = _warm(orch, cold=False)
         t_fc = []
         for c in range(cycles):
@@ -600,7 +550,7 @@ def pricing_drift(*, profiles: pathlib.Path | None = None,
         ))
     lat_mean = {}
     for name, cm in (("analytic", None), ("calibrated", cal)):
-        orch = _saturated_fleet(n_sessions, seed, cost_model=cm)
+        orch = saturated_fleet(n_sessions, seed, cost_model=cm)
         _, lat, _ = orch.price_fleet()
         lat_mean[name] = float(np.mean(lat))
     rows.append(dict(
@@ -688,7 +638,7 @@ def thrash_ab(*, n_sessions: int = 16, cycles: int = 30,
     ]
     rows = []
     for fixed_point in (False, True):
-        orch = _saturated_fleet(n_sessions, seed, fixed_point=fixed_point)
+        orch = saturated_fleet(n_sessions, seed, fixed_point=fixed_point)
         for t in range(3):                      # warm / compile
             orch.step(now=float(t))
         live = sorted(orch.sessions)
@@ -741,51 +691,6 @@ def thrash_ab(*, n_sessions: int = 16, cycles: int = 30,
     return rows
 
 
-def _shard_catalog() -> list[tuple[str, object]]:
-    """Tiny transformer archs sized so 128 resident sessions fit one §IV
-    region (weights ~0.4–0.5 GB/session vs 440 GB of region memory)."""
-    def g(layers: int, name: str):
-        return make_transformer_graph(
-            name=name, num_layers=layers, d_model=256,
-            flops_per_layer_token=4e9, weight_bytes_per_layer=5e7,
-            embed_weight_bytes=5e7, head_weight_bytes=5e7,
-            head_flops_token=2e8,
-        )
-    return [("shard-a", g(6, "shard-a")), ("shard-b", g(8, "shard-b"))]
-
-
-def _fill_sharded(w: ShardedFleetOrchestrator, shard_sessions: int,
-                  seed: int) -> None:
-    """Bulk-admit ``shard_sessions`` sessions into EVERY region.
-
-    The §IV region replicas are byte-identical at t=0, so the batched DP
-    solves ONE region's session set and the (region-local) solutions are
-    reused verbatim across all regions — admission cost stays O(sessions)
-    in rollouts + row writes, not O(sessions) in DP solves.
-    """
-    catalog = _shard_catalog()
-    rng = np.random.default_rng(seed)
-    metas, probs = [], []
-    for i in range(shard_sessions):
-        arch, graph = catalog[i % len(catalog)]
-        wl = Workload(
-            tokens_in=int(rng.integers(16, 48)),
-            tokens_out=int(rng.integers(4, 8)),
-            arrival_rate=0.05,                 # resident, not saturating
-        )
-        src = i % 3                            # MEC ingress nodes only
-        metas.append((arch, graph, wl, src))
-        probs.append(SessionProblem(graph, wl, source_node=src))
-    inner0 = w.inners[0]
-    sols = inner0.splitter.solve_batch(
-        probs, inner0.profiler.system_state(), max_units=inner0.max_units)
-    sols = [coalesce_same_node(s) for s in sols]
-    for inner in w.inners:
-        for (arch, graph, wl, src), sol in zip(metas, sols):
-            inner.admit(graph, wl, source_node=src, arch=arch, now=0.0,
-                        solution=sol)
-
-
 def shard_scaling(*, shard_sessions: int = 128, regions=(8, 32, 80),
                   cycles: int = 12, hot_regions: int = 2,
                   seed: int = 0) -> list[dict]:
@@ -809,20 +714,8 @@ def shard_scaling(*, shard_sessions: int = 128, regions=(8, 32, 80),
     """
     rows = []
     for n_regions in regions:
-        w = build_regional_orchestrator(MECScenarioParams(), n_regions)
-        _fill_sharded(w, shard_sessions, seed)
-        for r in range(min(hot_regions, n_regions)):
-            w.inners[r].forecaster = CapacityForecaster(ForecastConfig(
-                horizon_steps=4, season_steps=8, sample_interval_s=1.0))
-        trace = diurnal(seed=seed + 1, base=0.45, amp=0.15, period_s=24.0,
-                        spike_rate_per_period=1.0, spike_amp=0.15,
-                        spike_width_s=2.0, horizon_s=120.0)
-
-        def drive_hot(t: float) -> None:
-            for r in range(min(hot_regions, n_regions)):
-                st = w.inners[r].profiler.base_state
-                st.background_util[:3] = trace(t)
-
+        w, drive_hot = hot_sharded_fleet(n_regions, shard_sessions, seed,
+                                         hot_regions=hot_regions)
         t = 1.0
         for _ in range(3):                     # warm: compile + settle
             drive_hot(t)
@@ -853,7 +746,7 @@ def shard_scaling(*, shard_sessions: int = 128, regions=(8, 32, 80),
 
     # regions=1 comparability row: the monitor section's saturated fleet,
     # stepped through the (verbatim-delegating) wrapper
-    orch = _saturated_fleet(shard_sessions, seed)
+    orch = saturated_fleet(shard_sessions, seed)
     w1 = ShardedFleetOrchestrator(
         [orch], region_of=np.zeros(
             orch.profiler.base_state.num_nodes, dtype=np.int64))
@@ -901,6 +794,7 @@ def main() -> None:  # pragma: no cover
                          "sessions at a fixed triggered-set size, plus the "
                          "shards=1 comparability row")
     args = ap.parse_args()
+    enable_compile_cache()
     run_all = not (args.amortization or args.monitor or args.qos
                    or args.storm or args.drift or args.chaos or args.thrash
                    or args.shards)

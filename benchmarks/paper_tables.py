@@ -9,6 +9,7 @@ from __future__ import annotations
 import numpy as np
 
 from repro.edgesim import MECScenarioParams, build_mec_scenario
+from repro.launch.compile_cache import enable_compile_cache
 
 BACKHAULS = (20.0, 50.0, 100.0, 200.0)
 PAPER_TABLE2 = {  # bw -> (static ms, adaptive ms, thr x, gpu util)
@@ -112,6 +113,7 @@ def orchestration_overhead() -> list[dict]:
 
 
 def main() -> None:  # pragma: no cover - exercised via benchmarks.run
+    enable_compile_cache()
     for name, fn in [("table2", table2_kpis), ("fig3", fig3_latency_vs_bandwidth),
                      ("overhead", orchestration_overhead)]:
         print(f"== {name} ==")

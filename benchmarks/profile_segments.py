@@ -30,6 +30,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_bundle
 from repro.core.profiling import SegmentProfile
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import SegmentProfiler
 
 DEFAULT_ARCHS = ("llama3-8b", "mamba2-1.3b", "recurrentgemma-9b",
@@ -65,6 +66,7 @@ def main() -> None:  # pragma: no cover
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also dump this run's document to PATH")
     args = ap.parse_args()
+    enable_compile_cache()
 
     archs = ([SMOKE_ARCH] if args.smoke
              else tuple(args.arch) if args.arch else DEFAULT_ARCHS)

@@ -23,6 +23,9 @@ def _csv(name: str, us: float, derived: str) -> None:
 
 def main() -> None:
     from benchmarks import paper_tables, roofline, solver_scaling
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
 

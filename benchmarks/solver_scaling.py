@@ -13,6 +13,7 @@ import numpy as np
 
 from repro.core import SystemState, Workload
 from repro.core.graph import make_transformer_graph
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _random_state(n: int, seed: int) -> SystemState:
@@ -66,6 +67,7 @@ def solver_scaling() -> list[dict]:
 
 
 def main() -> None:  # pragma: no cover
+    enable_compile_cache()
     for r in solver_scaling():
         print(r)
 

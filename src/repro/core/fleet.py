@@ -2438,16 +2438,15 @@ class ShardedFleetOrchestrator:
         local_src = min(int(sess.source_node), state_t.num_nodes - 1)
         eff = tgt.effective_state(
             state_t, _table=tgt.resident_table(state_t))
-        try:
-            [sol] = tgt.splitter.solve_batch(
-                [SessionProblem(
-                    sess.graph, sess.workload, source_node=local_src,
-                    input_bytes_per_token=sess.input_bytes_per_token,
-                    prepacked=sess.prepacked)],
-                eff, max_units=tgt.max_units,
-            )
-        except Exception:
-            return False
+        # the DP prices an infeasible placement at +_BIG and never raises for
+        # it, so an exception here is a fault and propagates
+        [sol] = tgt.splitter.solve_batch(
+            [SessionProblem(
+                sess.graph, sess.workload, source_node=local_src,
+                input_bytes_per_token=sess.input_bytes_per_token,
+                prepacked=sess.prepacked)],
+            eff, max_units=tgt.max_units,
+        )
         sol = coalesce_same_node(sol)
         sol = tgt.repair_solution(
             sess.graph, sol, eff, sess.workload, source_node=local_src,

@@ -380,7 +380,7 @@ class FleetCostEvaluator:
     ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
         """Returns (latency (B,), total Φ (B,), node ρ (B, n))."""
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         B, K = packed.seg_flops.shape
         n = state.num_nodes
@@ -612,7 +612,7 @@ class BatchedMigrationSolver:
         (see :func:`_surrogate_inputs`); ``None`` keeps the memory-blind
         PR-2 surrogate, bit-compatible with the scalar reference DP."""
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         B, K = packed.seg_flops.shape
         n = state.num_nodes
@@ -862,7 +862,7 @@ class BatchedRepairPass:
         """Repaired assignments (B, K) for the packed rows' current
         ``seg_node`` against per-row residual memory ``mem`` (B, n)."""
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         B, K = packed.seg_flops.shape
         a, Bp = self._padded(packed, bg, link_bw, mem)
@@ -888,7 +888,7 @@ class BatchedRepairPass:
         assignment) in one fused dispatch — the batched Φ mirror prices
         exactly what :class:`FleetCostEvaluator` would."""
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         B, K = packed.seg_flops.shape
         n = state.num_nodes
@@ -943,7 +943,7 @@ class FleetStateBuffers:
 
     def __init__(self, *, rows: int = 8, segs: int = 4) -> None:
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         rows = _pow2(max(1, rows))
         segs = _pow2(max(1, segs))
@@ -986,7 +986,7 @@ class FleetStateBuffers:
 
     def _grow_rows(self, need: int) -> None:
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         old = self.n_rows
         new = _pow2(max(need, 2 * old))
@@ -1006,7 +1006,7 @@ class FleetStateBuffers:
 
     def _grow_segs(self, need: int) -> None:
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         old = self.max_segs
         new = _pow2(need)
@@ -1033,7 +1033,7 @@ class FleetStateBuffers:
     ) -> None:
         """Write one session's current config into its row (allocating one)."""
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         t0 = time.perf_counter()
         self._grow_segs(len(boundaries) - 1)
@@ -1062,7 +1062,7 @@ class FleetStateBuffers:
     def remove(self, sid: int) -> None:
         """Free a departed session's row (zeroed: inactive rows stay zeros)."""
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         row = self.row_of.pop(sid)
         with enable_x64(True):
@@ -1088,7 +1088,7 @@ class FleetStateBuffers:
         reference the incremental path is equivalence-tested against.
         """
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         t0 = time.perf_counter()
         n = len(items)
@@ -1644,7 +1644,7 @@ class ResidentFleetKernel:
         """C(t) vectors uploaded once per cycle; ``price`` and ``migrate``
         share the same upload when the caller passes it through."""
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         with enable_x64(True):
             return (
@@ -1674,7 +1674,7 @@ class ResidentFleetKernel:
         same dispatch; ``now`` gates ring advancement (``None`` → read-only
         dispatch that observes but does not append)."""
         import jax
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         n = state.num_nodes
         if state_args is None:
@@ -1733,7 +1733,7 @@ class ResidentFleetKernel:
         compiled program, different input rows — so a proactive migration
         never targets a node that is about to spike."""
         import jax
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         n = state.num_nodes
         key = (buf.n_rows, buf.max_segs, n, weights, float(mem_penalty))
@@ -1789,7 +1789,7 @@ class ResidentFleetKernel:
         """
         import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         n = state.num_nodes
         key = (buf.n_rows, buf.max_segs, n, weights, float(mem_penalty),
@@ -1927,7 +1927,7 @@ class ShardedFleetState:
         """Price every shard against its regional C(t) in ONE dispatch."""
         import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         S = self.n_shards
         if len(states) != S:

@@ -215,7 +215,7 @@ class CapacityForecaster:
 
     def ensure(self, n: int) -> None:
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         if self.util_ring is not None:
             return
@@ -327,7 +327,7 @@ class CapacityForecaster:
         a mismatched snapshot is an error, not a silent re-warm-up.
         """
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         if not d:
             return
@@ -367,7 +367,7 @@ class CapacityForecaster:
         math to the fused kernel path.  Returns whether the sample advanced
         the ring (False → cadence-gated no-op)."""
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         bg = np.asarray(bg_util, dtype=np.float64)
         n = bg.shape[0]
@@ -401,7 +401,7 @@ class CapacityForecaster:
     def predict_util(self) -> np.ndarray:
         """(H, n) background-utilization forecast for t+1 … t+H (host copy,
         residual-corrected, unclipped readiness: caller checks ``ready``)."""
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         if self.util_ring is None or not self.enabled:
             raise RuntimeError("forecaster has no samples / horizon is 0")

@@ -10,10 +10,12 @@ from .scenario import (
     build_mec_scenario,
     build_regional_orchestrator,
     fleet_model_catalog,
+    hot_sharded_fleet,
     llama3_8b_graph,
     mec_traces,
     regional_system_state,
     regional_traces,
+    saturated_fleet,
     spike_onsets,
     static_baseline_split,
 )
@@ -38,7 +40,8 @@ __all__ = [
     "SimResult", "TickMetrics", "Trace", "base_system_state",
     "build_fleet_scenario", "build_mec_scenario",
     "build_regional_orchestrator", "constant", "diurnal",
-    "fleet_model_catalog", "llama3_8b_graph", "mec_traces", "ou_process",
-    "regional_system_state", "regional_traces",
+    "fleet_model_catalog", "hot_sharded_fleet", "llama3_8b_graph",
+    "mec_traces", "ou_process", "regional_system_state", "regional_traces",
+    "saturated_fleet",
     "spike_onsets", "square_wave", "static_baseline_split",
 ]

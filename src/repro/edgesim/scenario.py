@@ -28,6 +28,7 @@ blind-admit behavior).
 from __future__ import annotations
 
 from dataclasses import dataclass
+from typing import Callable
 
 import numpy as np
 
@@ -37,20 +38,22 @@ from ..core.admission import (
 )
 from ..core.broadcast import InProcessAgent, ReconfigurationBroadcast
 from ..core.cost_model import CostWeights, SystemState, Workload
+from ..core.forecast import CapacityForecaster, ForecastConfig
 from ..core.graph import ModelGraph, make_transformer_graph
 from ..core.orchestrator import AdaptiveOrchestrator
 from ..core.profiling import CapacityProfiler
-from ..core.splitter import SplitRevision
+from ..core.splitter import SessionProblem, SplitRevision, coalesce_same_node
 from ..core.triggers import Thresholds
 from ..core.fleet import FleetOrchestrator, ShardedFleetOrchestrator
 from .simulator import EdgeSimulator, FleetSimConfig, FleetSimulator, SimConfig
-from .traces import Trace, constant, ou_process, square_wave
+from .traces import Trace, constant, diurnal, ou_process, square_wave
 
 __all__ = [
     "MECScenarioParams", "llama3_8b_graph", "build_mec_scenario",
     "static_baseline_split", "FleetScenarioParams", "build_fleet_scenario",
     "fleet_model_catalog", "mec_traces", "spike_onsets",
     "regional_system_state", "regional_traces", "build_regional_orchestrator",
+    "saturated_fleet", "hot_sharded_fleet",
 ]
 
 MBPS = 1e6 / 8.0  # bytes/s per Mb/s
@@ -327,6 +330,122 @@ def build_regional_orchestrator(
         inners, region_of=gstate.region_of)
     wrapper.profiler.base_state = gstate
     return wrapper
+
+
+# --------------------------------------------------------------------------- #
+# benchmark fleets: built in bulk at t=0 and stepped by hand
+# --------------------------------------------------------------------------- #
+def saturated_fleet(n_sessions: int, seed: int, *,
+                    forecast: bool = False,
+                    cost_model=None,
+                    fixed_point: bool = True) -> FleetOrchestrator:
+    """A fleet of ``n_sessions`` live sessions on the §IV topology, loaded
+    hard enough that latency/util triggers fire every monitoring cycle.
+
+    Solver throttling is disabled and the cool-down kept below the cycle
+    spacing so every cycle exercises the full decision hot path (trigger →
+    migrate DP → re-split → hysteresis)."""
+    state = base_system_state(MECScenarioParams())
+    orch = FleetOrchestrator(
+        profiler=CapacityProfiler(base_state=state),
+        broadcast=ReconfigurationBroadcast(
+            [InProcessAgent(i) for i in range(state.num_nodes)]
+        ),
+        thresholds=Thresholds(cooldown_s=0.5),
+        solve_backoff_s=0.0,
+        # short season so the predictor goes live inside the warmup steps
+        # and the measured cycles pay the FULL forecast path (fused ring
+        # update + worst-case re-pricing + forecast-priced migrate)
+        forecaster=(CapacityForecaster(ForecastConfig(
+            horizon_steps=8, season_steps=8)) if forecast else None),
+        cost_model=cost_model,
+        use_fixed_point=fixed_point,
+    )
+    rng = np.random.default_rng(seed)
+    catalog = fleet_model_catalog()
+    for _ in range(n_sessions):
+        _, graph = catalog[int(rng.integers(len(catalog)))]
+        wl = Workload(
+            tokens_in=int(rng.integers(32, 96)),
+            tokens_out=int(rng.integers(8, 16)),
+            arrival_rate=float(rng.uniform(2.0, 5.0)),  # deliberately hot
+        )
+        orch.admit(graph, wl, source_node=int(rng.integers(0, 3)), now=0.0)
+    return orch
+
+
+def _shard_catalog() -> list[tuple[str, ModelGraph]]:
+    """Tiny transformer archs sized so 128 resident sessions fit one §IV
+    region (weights ~0.4–0.5 GB/session vs 440 GB of region memory)."""
+    def g(layers: int, name: str):
+        return make_transformer_graph(
+            name=name, num_layers=layers, d_model=256,
+            flops_per_layer_token=4e9, weight_bytes_per_layer=5e7,
+            embed_weight_bytes=5e7, head_weight_bytes=5e7,
+            head_flops_token=2e8,
+        )
+    return [("shard-a", g(6, "shard-a")), ("shard-b", g(8, "shard-b"))]
+
+
+def _fill_sharded(w: ShardedFleetOrchestrator, shard_sessions: int,
+                 seed: int) -> None:
+    """Bulk-admit ``shard_sessions`` sessions into EVERY region.
+
+    The §IV region replicas are byte-identical at t=0, so the batched DP
+    solves ONE region's session set and the (region-local) solutions are
+    reused verbatim across all regions — admission cost stays O(sessions)
+    in rollouts + row writes, not O(sessions) in DP solves.
+    """
+    catalog = _shard_catalog()
+    rng = np.random.default_rng(seed)
+    metas, probs = [], []
+    for i in range(shard_sessions):
+        arch, graph = catalog[i % len(catalog)]
+        wl = Workload(
+            tokens_in=int(rng.integers(16, 48)),
+            tokens_out=int(rng.integers(4, 8)),
+            arrival_rate=0.05,                 # resident, not saturating
+        )
+        src = i % 3                            # MEC ingress nodes only
+        metas.append((arch, graph, wl, src))
+        probs.append(SessionProblem(graph, wl, source_node=src))
+    inner0 = w.inners[0]
+    sols = inner0.splitter.solve_batch(
+        probs, inner0.profiler.system_state(), max_units=inner0.max_units)
+    sols = [coalesce_same_node(s) for s in sols]
+    for inner in w.inners:
+        for (arch, graph, wl, src), sol in zip(metas, sols):
+            inner.admit(graph, wl, source_node=src, arch=arch, now=0.0,
+                        solution=sol)
+
+
+def hot_sharded_fleet(
+    n_regions: int, shard_sessions: int, seed: int, *, hot_regions: int = 2,
+) -> tuple[ShardedFleetOrchestrator, Callable[[float], None]]:
+    """``n_regions`` §IV regions of ``shard_sessions`` resident sessions.
+
+    The first ``hot_regions`` regions carry a live
+    :class:`CapacityForecaster` and a :func:`diurnal` background load on
+    their MEC nodes, so they run a full per-shard step every cycle while
+    every other shard is resolved by the one vmapped screen.  Returns the
+    orchestrator and ``drive(t)``, which sets the hot regions' background
+    load for time ``t``; call it before each ``step(t)``.
+    """
+    w = build_regional_orchestrator(MECScenarioParams(), n_regions)
+    _fill_sharded(w, shard_sessions, seed)
+    hot = w.inners[:hot_regions]
+    for o in hot:
+        o.forecaster = CapacityForecaster(ForecastConfig(
+            horizon_steps=4, season_steps=8, sample_interval_s=1.0))
+    trace = diurnal(seed=seed + 1, base=0.45, amp=0.15, period_s=24.0,
+                    spike_rate_per_period=1.0, spike_amp=0.15,
+                    spike_width_s=2.0, horizon_s=120.0)
+
+    def drive(t: float) -> None:
+        for o in hot:
+            o.profiler.base_state.background_util[:3] = trace(t)
+
+    return w, drive
 
 
 # --------------------------------------------------------------------------- #

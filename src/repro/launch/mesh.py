@@ -23,7 +23,7 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices for mesh {shape}; have {len(jax.devices())}. "
             "The dry-run sets XLA_FLAGS=--xla_force_host_platform_device_count."
         )
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:n])
+    return _mesh(shape, axes, n)
 
 
 def make_small_mesh(data: int = 2, model: int = 2, pod: int | None = None):
@@ -32,5 +32,11 @@ def make_small_mesh(data: int = 2, model: int = 2, pod: int | None = None):
         shape, axes = (pod, data, model), ("pod", "data", "model")
     else:
         shape, axes = (data, model), ("data", "model")
-    n = int(np.prod(shape))
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:n])
+    return _mesh(shape, axes, int(np.prod(shape)))
+
+
+def _mesh(shape, axes, n):
+    # Auto axes: the models pin activations with with_sharding_constraint,
+    # which refuses the Explicit axes jax.make_mesh now makes by default
+    return jax.make_mesh(shape, axes, devices=jax.devices()[:n],
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

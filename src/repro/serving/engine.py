@@ -44,7 +44,13 @@ class SplitInferenceEngine:
 
     # -------------------------------------------------------------- config --
     def apply_config(self, cfg: PartitionConfig) -> None:
-        """Stage per-node segment params and activate the new split."""
+        """Stage per-node segment params and activate the new split.
+
+        The old chain's staged slices are dropped first: at full width a
+        second staged copy alongside the full tree does not fit one device.
+        """
+        self.chain = None
+        self.node_params = {}
         self.chain = SegmentChain(self.bundle, self.params, cfg.boundaries,
                                   transfer_hook=self.transport)
         staged: dict[int, list] = {}

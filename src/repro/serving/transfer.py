@@ -33,7 +33,7 @@ class ActivationTransport:
     """transfer_hook for ``segments.run_chain``."""
 
     compress: bool = False
-    interpret: bool = True      # Pallas interpret mode (CPU container)
+    interpret: bool = False     # Pallas interpreter; CPU callers opt in
     stats: TransferStats = field(default_factory=TransferStats)
 
     def __call__(self, boundary: int, x):

@@ -37,7 +37,7 @@ from repro.core.fleet_eval import _BIG, _make_fixed_point
 
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
-from jax.experimental import enable_x64  # noqa: E402
+from jax import enable_x64  # noqa: E402
 
 
 # --------------------------------------------------------------------- #

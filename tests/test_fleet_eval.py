@@ -145,7 +145,7 @@ def test_device_surrogate_expansion_matches_host_reference(seed):
     reproduces the pinned host reference _surrogate_inputs, with and
     without the Eq. 4 memory mask."""
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     from repro.core.fleet_eval import _BIG, _surrogate_batch, _surrogate_inputs
 

@@ -119,13 +119,32 @@ def test_engine_reconfigure_preserves_outputs():
         b.model_graph().total_weight_bytes)
 
 
+def test_serve_deploy_answers_through_the_compressed_chain():
+    """`launch.serve` builds the engine behind the adaptive orchestrator and
+    answers every request through the int8 chain (interpreted on CPU)."""
+    from repro.launch.serve import deploy
+
+    dep = deploy("llama3-8b", reduced=True, compress=True, interpret=True,
+                 prompt_len=8)
+    vocab = dep.bundle.cfg.vocab
+    for i in range(3):
+        toks = jax.random.randint(jax.random.PRNGKey(i), (1, 8), 0, vocab)
+        logits, priced = dep.serve(toks, now=float(i))
+        assert logits.shape == (1, 8, vocab)
+        assert np.isfinite(np.asarray(logits)).all() and priced > 0.0
+    assert dep.engine.config == dep.orch.current
+    stats = dep.engine.transfer_stats()
+    assert stats.transfers >= 3       # every request crossed a boundary
+    assert stats.compression_ratio > 1.7
+
+
 def test_transport_compression_accounting():
     b, params = _bundle_params("llama3-8b")
     L = len(b.model_graph())
     toks = jax.random.randint(_KEY, (2, 16), 0, b.cfg.vocab)
-    raw = ActivationTransport(compress=False)
+    raw = ActivationTransport(compress=False, interpret=True)
     run_chain(b, params, (0, 2, L), toks, transfer_hook=raw)
-    comp = ActivationTransport(compress=True)
+    comp = ActivationTransport(compress=True, interpret=True)
     out_c = run_chain(b, params, (0, 2, L), toks, transfer_hook=comp)
     out_r = run_chain(b, params, (0, 2, L), toks, transfer_hook=None)
     assert comp.stats.compression_ratio > 1.7       # ~2x minus scale overhead

@@ -1,0 +1,84 @@
+"""Main-path programs compile for a TPU v5e at their real shapes.
+
+Nothing runs: the TPU compiler installed with JAX compiles for a described
+v5e:2x2 topology with no chip attached, and refuses what the chip's compiler
+would refuse (tiling, fast-memory limits, unsupported dtypes).  The topology
+is described inside a fixture, so collecting this file never loads the TPU
+library; where it cannot be described, the tests skip.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get
+from repro.core.cost_model import CostWeights
+from repro.core.fleet_eval import _make_fused_price
+from repro.kernels import ops
+
+# the serve phase of chip_smoke.py: stablelm-3b, one 128-token prompt
+_SERVE_ROWS = 128
+_D_MODEL = get("stablelm-3b").d_model
+# the saturated 128-session fleet on the 4-node §IV topology: its resident
+# buffers hold 128 rows of 4 segments after a few monitoring cycles
+_SESSIONS, _SEGS, _NODES = 128, 4, 4
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("kernel", ["quantize", "dequantize"])
+def test_int8_transport_compiles_for_v5e(one_chip, kernel):
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows = (_SERVE_ROWS, _D_MODEL)
+    if kernel == "quantize":
+        lowered = ops.quantize_int8.lower(sds(rows, jnp.bfloat16))
+    else:
+        lowered = ops.dequantize_int8.lower(
+            sds(rows, jnp.int8), sds((_SERVE_ROWS, 1), jnp.float32),
+            jnp.bfloat16)
+    compiled = lowered.compile()
+    assert "tpu_custom_call" in compiled.as_text()   # the Mosaic kernel
+    assert compiled.memory_analysis() is not None
+
+
+def test_resident_price_compiles_for_v5e(one_chip):
+    """The fused price program every monitoring cycle dispatches, in the
+    float64 scope it runs in."""
+    w = CostWeights()
+    B, K, n = _SESSIONS, _SEGS, _NODES
+    with jax.enable_x64(True):
+        f64, i64 = jnp.float64, jnp.int64
+
+        def sds(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        rows = (sds((B, K), f64), sds((B, K), f64), sds((B, K), bool),
+                sds((B, K), i64), sds((B, K), bool), sds((B, K), f64),
+                sds((B,), f64), sds((B,), f64), sds((B,), f64),
+                sds((B,), i64), sds((B,), bool))
+        state = (sds((n,), f64), sds((n, n), f64), sds((n, n), f64),
+                 sds((n,), f64), sds((n,), f64), sds((n,), bool),
+                 sds((n,), f64))
+        price = jax.jit(_make_fused_price(n, w.alpha, w.beta, w.gamma,
+                                          1e3, 0.05))
+        compiled = price.lower(*rows, *state).compile()
+    assert compiled.memory_analysis() is not None
