@@ -4,6 +4,8 @@ with node-failure and straggler drills.
 Run:  PYTHONPATH=src python examples/edge_orchestration.py
 """
 
+import itertools
+
 import numpy as np
 
 from repro.core import DecisionKind
@@ -31,7 +33,8 @@ sim.util_traces[1] = type(orig_trace)(
     lambda t: 0.99 if t >= 40.0 else orig_trace(t), 0.0, 0.99)
 res = sim.run()
 uses_node1_before = any(
-    1 in d.config.assignment for d in sim.orch.decisions[:35] if d.config)
+    1 in d.config.assignment for d in itertools.islice(sim.orch.decisions, 35)
+    if d.config)
 final_cfg = sim.orch.current
 print(f"node 1 used before failure: {uses_node1_before}")
 print(f"final assignment (post-failure): {final_cfg.assignment} "

@@ -10,6 +10,7 @@ re-split (Eq. 8).  Committed changes go through the Reconfiguration Broadcast.
 from __future__ import annotations
 
 import time
+from collections import deque
 from dataclasses import dataclass, field
 from enum import Enum
 
@@ -22,6 +23,10 @@ from .splitter import SplitRevision
 from .triggers import SolveThrottle, Thresholds, decision_gate, hysteresis_keep
 
 __all__ = ["DecisionKind", "Decision", "AdaptiveOrchestrator"]
+
+# the decision log keeps the most recent cycles only: a served deployment
+# steps once a request, for as long as it runs
+DECISION_LOG = 1024
 
 
 class DecisionKind(Enum):
@@ -64,7 +69,11 @@ class AdaptiveOrchestrator:
 
     current: PartitionConfig | None = None
     t_last_reconfig: float = float("-inf")
-    decisions: list[Decision] = field(default_factory=list)
+    decisions: deque[Decision] = field(
+        default_factory=lambda: deque(maxlen=DECISION_LOG))
+    # every decision since deployment, by kind (``DecisionKind.value``)
+    decision_counts: dict[str, int] = field(
+        default_factory=lambda: dict.fromkeys((k.value for k in DecisionKind), 0))
 
     # ------------------------------------------------------------------ #
     def deploy_initial(self, boundaries, assignment, now: float = 0.0) -> PartitionConfig:
@@ -86,6 +95,11 @@ class AdaptiveOrchestrator:
         return cfg
 
     # ------------------------------------------------------------------ #
+    def _record(self, d: Decision) -> Decision:
+        self.decisions.append(d)
+        self.decision_counts[d.kind.value] += 1
+        return d
+
     def _predicted_latency(self, sol: Solution, state: SystemState) -> float:
         return phi(self.graph, sol.boundaries, sol.assignment, state,
                    self.workload, self.weights).latency
@@ -104,19 +118,16 @@ class AdaptiveOrchestrator:
                              throttle=self.throttle)
         reasons = tuple(env.reasons)
         if gate == "cooldown":
-            d = Decision(DecisionKind.COOLDOWN, self.current, reasons, 0.0,
-                         time.perf_counter() - t0)
-            self.decisions.append(d)
-            return d
+            return self._record(Decision(DecisionKind.COOLDOWN, self.current,
+                                         reasons, 0.0, time.perf_counter() - t0))
         if gate != "solve":  # "keep" (no trigger) or "throttled" (reuse answer)
-            d = Decision(DecisionKind.KEEP, self.current,
-                         reasons if gate == "throttled" else (),
-                         self._predicted_latency(
-                             Solution(self.current.boundaries,
-                                      self.current.assignment, 0.0), state),
-                         time.perf_counter() - t0)
-            self.decisions.append(d)
-            return d
+            return self._record(Decision(
+                DecisionKind.KEEP, self.current,
+                reasons if gate == "throttled" else (),
+                self._predicted_latency(
+                    Solution(self.current.boundaries,
+                             self.current.assignment, 0.0), state),
+                time.perf_counter() - t0))
 
         # --- attempt 1: placement migration under the current split (Eq. 7) ---
         mig = solve_placement_chain_dp(
@@ -149,20 +160,15 @@ class AdaptiveOrchestrator:
             (chosen.boundaries, chosen.assignment),
             chosen_lat, cur_lat, self.min_improvement_frac,
         ):
-            d = Decision(DecisionKind.KEEP, self.current, reasons, chosen_lat,
-                         solver_time)
-            self.decisions.append(d)
-            return d
+            return self._record(Decision(DecisionKind.KEEP, self.current,
+                                         reasons, chosen_lat, solver_time))
 
         cfg = self.broadcast.rollout(chosen.boundaries, chosen.assignment,
                                      reason="; ".join(reasons), now=now)
         if cfg is None:  # rollout aborted (node failure mid-broadcast) — keep
-            d = Decision(DecisionKind.KEEP, self.current, reasons, chosen_lat,
-                         solver_time)
-            self.decisions.append(d)
-            return d
+            return self._record(Decision(DecisionKind.KEEP, self.current,
+                                         reasons, chosen_lat, solver_time))
         self.current = cfg
         self.t_last_reconfig = now
-        d = Decision(kind, cfg, reasons, chosen_lat, solver_time)
-        self.decisions.append(d)
-        return d
+        return self._record(Decision(kind, cfg, reasons, chosen_lat,
+                                     solver_time))

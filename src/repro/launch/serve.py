@@ -22,6 +22,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs import get_bundle
 from repro.core import (
@@ -54,16 +55,29 @@ class Deployment:
     def serve(self, tokens: jax.Array, now: float) -> tuple[jax.Array, float]:
         """Answer one request through the split chain, then run one
         monitoring cycle and re-split the engine if the orchestrator
-        committed a new config.  Returns (fp32 logits, priced latency)."""
-        logits = self.engine.infer_logits(tokens)
-        c = self.orch.current
-        lat = chain_latency(self.orch.graph, c.boundaries, c.assignment,
-                            self.profiler.system_state(), self.workload)
-        self.profiler.observe_latency(lat)
-        self.profiler.observe_links(self.state.link_bw)
-        d = self.orch.step(now=now)
-        if d.config is not None and d.config.version != self.engine.config.version:
-            self.engine.apply_config(d.config)
+        committed a new config.  Returns (fp32 logits, priced latency).
+
+        The call is the profiler span ``serve`` (arguments ``req``, this
+        deployment's sequence number of the request, and ``rows``, its
+        padded length); the chain's ``segment`` and ``transport`` spans and
+        the monitoring cycle's ``control`` span, with ``restage`` around a
+        re-split, nest inside it.
+        """
+        stats = self.engine.stats
+        with TraceAnnotation("serve", req=stats.requests, rows=tokens.shape[1]):
+            stats.requests += 1
+            logits = self.engine.infer_logits(tokens)
+            with TraceAnnotation("control"):
+                c = self.orch.current
+                lat = chain_latency(self.orch.graph, c.boundaries, c.assignment,
+                                    self.profiler.system_state(), self.workload)
+                self.profiler.observe_latency(lat)
+                self.profiler.observe_links(self.state.link_bw)
+                d = self.orch.step(now=now)
+                if (d.config is not None
+                        and d.config.version != self.engine.config.version):
+                    with TraceAnnotation("restage", version=d.config.version):
+                        self.engine.apply_config(d.config)
         return logits, lat
 
 
@@ -128,12 +142,18 @@ def main(argv=None) -> dict:
     engine = dep.engine
     stats = engine.transfer_stats()
     out = {
-        "requests": args.requests,
+        "requests": engine.stats.requests,
         "mean_latency_ms": round(float(np.mean(lat)) * 1e3, 1),
         "reconfigurations": engine.reconfigurations,
         "wire_MB": round(stats.wire_bytes / 1e6, 2),
         "compression_ratio": round(stats.compression_ratio, 2),
         "final_split": str(engine.config.boundaries),
+        # the served path's counters: a segment traced again on every call
+        # shows as as many traces as calls
+        "segment_calls": engine.stats.segment_calls,
+        "segment_traces": engine.stats.segment_traces,
+        "transfers": stats.transfers,
+        "decisions": dict(dep.orch.decision_counts),
     }
     print(out)
     return out

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from ..core.broadcast import PartitionConfig
 from ..core.graph import ModelGraph
 from ..models.api import ModelBundle
-from .segments import SegmentChain, SegmentRunner
+from .segments import SegmentChain, SegmentRunner, ServingStats
 from .transfer import ActivationTransport, TransferStats
 
 __all__ = ["SplitInferenceEngine"]
@@ -38,6 +38,9 @@ class SplitInferenceEngine:
     node_params: dict[int, list] = field(default_factory=dict)
     reconfigurations: int = 0
     chain: SegmentChain | None = None
+    # counts of the served path; every chain the engine stages counts here,
+    # so they outlive a re-split
+    stats: ServingStats = field(default_factory=ServingStats)
 
     def graph(self) -> ModelGraph:
         return self.bundle.model_graph()
@@ -52,7 +55,8 @@ class SplitInferenceEngine:
         self.chain = None
         self.node_params = {}
         self.chain = SegmentChain(self.bundle, self.params, cfg.boundaries,
-                                  transfer_hook=self.transport)
+                                  transfer_hook=self.transport,
+                                  stats=self.stats)
         staged: dict[int, list] = {}
         for j, (node, seg) in enumerate(zip(cfg.assignment,
                                             self.chain.segments)):

@@ -16,12 +16,29 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from ..models import griffin, mamba2, transformer
 from ..models.api import ModelBundle
 
-__all__ = ["BoundSegment", "SegmentChain", "SegmentRunner", "split_params",
-           "run_chain"]
+__all__ = ["BoundSegment", "SegmentChain", "SegmentRunner", "ServingStats",
+           "split_params", "run_chain"]
+
+
+@dataclass
+class ServingStats:
+    """Plain counters of the served path, kept by whoever serves.
+
+    ``requests`` counts the requests answered (``Deployment.serve``),
+    ``segment_calls`` the segments run, and ``segment_traces`` the times
+    JAX traced a segment's scan body: that Python runs only while JAX
+    traces, so the count is exact and costs nothing once a body is traced.
+    Griffin segments run layer by layer with no scan body and count none.
+    """
+
+    requests: int = 0
+    segment_calls: int = 0
+    segment_traces: int = 0
 
 
 def _tf_slice_blocks(params: Any, lo: int, hi: int) -> Any:
@@ -45,6 +62,7 @@ class SegmentRunner:
     lo: int
     hi: int
     local: bool = False
+    stats: ServingStats = dataclasses.field(default_factory=ServingStats)
 
     @property
     def n_units(self) -> int:
@@ -83,6 +101,7 @@ class SegmentRunner:
                            else _tf_slice_blocks(params, slo, shi))
 
                     def body(h, inputs):
+                        self.stats.segment_traces += 1
                         lp, w = inputs
                         return transformer.block_forward(h, lp, cfg, window=w), None
 
@@ -103,6 +122,7 @@ class SegmentRunner:
                        else _tf_slice_blocks(params, blo, bhi))
 
                 def body(h, lp):
+                    self.stats.segment_traces += 1
                     return mamba2.block_forward(h, lp, cfg), None
 
                 x, _ = jax.lax.scan(body, x, sub)
@@ -214,6 +234,10 @@ class SegmentChain:
     :class:`~repro.serving.transfer.ActivationTransport` — sees the
     activations crossing boundary ``j`` and returns what arrives on the
     other side.
+
+    Each segment runs inside a profiler span ``segment`` (arguments ``j``,
+    ``lo``, ``hi``) and counts into ``stats``, which an owner that rebuilds
+    the chain passes on so its counts outlive the chain.
     """
 
     bundle: ModelBundle
@@ -221,6 +245,7 @@ class SegmentChain:
     boundaries: tuple[int, ...]
     transfer_hook: Any = None
     slice_params: bool = True
+    stats: ServingStats = dataclasses.field(default_factory=ServingStats)
     segments: list[BoundSegment] = dataclasses.field(default_factory=list)
 
     def __post_init__(self) -> None:
@@ -231,7 +256,8 @@ class SegmentChain:
             views = [self.params] * len(pairs)
         self.segments = [
             BoundSegment(SegmentRunner(self.bundle, lo, hi,
-                                       local=self.slice_params), view)
+                                       local=self.slice_params,
+                                       stats=self.stats), view)
             for (lo, hi), view in zip(pairs, views)
         ]
 
@@ -239,7 +265,9 @@ class SegmentChain:
         x = tokens
         n = len(self.bundle.model_graph())
         for j, seg in enumerate(self.segments):
-            x = seg(x)
+            with TraceAnnotation("segment", j=j, lo=seg.lo, hi=seg.hi):
+                self.stats.segment_calls += 1
+                x = seg(x)
             if self.transfer_hook is not None and seg.hi < n:
                 x = self.transfer_hook(j, x)
         return x

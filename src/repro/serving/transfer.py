@@ -10,6 +10,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field
 
+from jax.profiler import TraceAnnotation
 
 from ..kernels import ops as kops
 
@@ -30,26 +31,28 @@ class TransferStats:
 
 @dataclass
 class ActivationTransport:
-    """transfer_hook for ``segments.run_chain``."""
+    """transfer_hook for ``segments.run_chain``; each crossing runs inside a
+    profiler span ``transport`` (argument ``boundary``)."""
 
     compress: bool = False
     interpret: bool = False     # Pallas interpreter; CPU callers opt in
     stats: TransferStats = field(default_factory=TransferStats)
 
     def __call__(self, boundary: int, x):
-        b, s, d = x.shape
-        raw = b * s * d * x.dtype.itemsize
-        if self.compress:
-            q, scales = kops.quantize_int8(x.reshape(b * s, d),
-                                           interpret=self.interpret)
-            wire = q.size + scales.size * 4
-            x = kops.dequantize_int8(q, scales, x.dtype,
-                                     interpret=self.interpret).reshape(b, s, d)
-        else:
-            wire = raw
-        self.stats.transfers += 1
-        self.stats.raw_bytes += raw
-        self.stats.wire_bytes += wire
-        self.stats.per_boundary[boundary] = \
-            self.stats.per_boundary.get(boundary, 0.0) + wire
-        return x
+        with TraceAnnotation("transport", boundary=boundary):
+            b, s, d = x.shape
+            raw = b * s * d * x.dtype.itemsize
+            if self.compress:
+                q, scales = kops.quantize_int8(x.reshape(b * s, d),
+                                               interpret=self.interpret)
+                wire = q.size + scales.size * 4
+                x = kops.dequantize_int8(q, scales, x.dtype,
+                                         interpret=self.interpret).reshape(b, s, d)
+            else:
+                wire = raw
+            self.stats.transfers += 1
+            self.stats.raw_bytes += raw
+            self.stats.wire_bytes += wire
+            self.stats.per_boundary[boundary] = \
+                self.stats.per_boundary.get(boundary, 0.0) + wire
+            return x

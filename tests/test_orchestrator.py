@@ -133,3 +133,20 @@ def test_warmup_is_dp_only(monkeypatch):
     sr.revise(graph, state, wl, source_node=0)
     assert calls["local_search"] == 1
     assert len(sr._jax_dp._compiled) == 1
+
+
+def test_decision_log_is_capped_and_counts_every_kind(monkeypatch):
+    """The log keeps the most recent ``DECISION_LOG`` decisions; the counts
+    by kind cover every cycle since deployment."""
+    from repro.core import orchestrator as orch_mod
+
+    monkeypatch.setattr(orch_mod, "DECISION_LOG", 4)
+    orch, profiler, _ = _orchestrator(backhaul=20.0)
+    kinds = []
+    for t in range(10):
+        profiler.observe_latency(0.5 if t < 3 else 0.05)
+        kinds.append(orch.step(now=100.0 + t).kind)
+    assert [d.kind for d in orch.decisions] == kinds[-4:]
+    assert orch.decision_counts == {k.value: kinds.count(k) for k in DecisionKind}
+    assert sum(orch.decision_counts.values()) == 10
+    assert orch.decision_counts["cooldown"] >= 1     # re-split, then cool-down

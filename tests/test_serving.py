@@ -138,6 +138,43 @@ def test_serve_deploy_answers_through_the_compressed_chain():
     assert stats.compression_ratio > 1.7
 
 
+def test_serve_counters_count_the_served_path_and_survive_a_resplit():
+    """``Deployment.serve`` counts requests, segment calls and the traces of
+    the segments' scan bodies into the engine's stats, the transport its
+    crossings, and the orchestrator each decision by kind; a re-split keeps
+    every count."""
+    from repro.launch.serve import deploy
+
+    dep = deploy("stablelm-3b", reduced=True, compress=True, interpret=True,
+                 prompt_len=8)
+    eng, vocab = dep.engine, dep.bundle.cfg.vocab
+    L = len(dep.bundle.model_graph())
+
+    def counts():
+        s, t = eng.stats, eng.transfer_stats()
+        return (s.requests, s.segment_calls, s.segment_traces, t.transfers,
+                sum(dep.orch.decision_counts.values()))
+
+    seen = [counts()]
+    assert seen[0] == (0, 0, 0, 0, 0)
+    for i in range(4):
+        if i == 2:      # re-split between requests: the chain is rebuilt
+            eng.apply_config(PartitionConfig(eng.config.version + 1,
+                                             (0, 2, 3, L), (0, 3, 0)))
+        toks = jax.random.randint(jax.random.PRNGKey(i), (1, 8), 0, vocab)
+        dep.serve(toks, now=float(i))
+        seen.append(counts())
+    for before, after in zip(seen, seen[1:]):
+        assert all(b <= a for b, a in zip(before, after))
+    requests, calls, traces, transfers, decisions = seen[-1]
+    assert requests == 4 and calls == 3 * requests
+    assert transfers == 2 * requests and decisions == requests
+    assert 1 <= traces <= calls
+    assert traces >= 2 * requests     # every call traces its scan again
+    # the next request's cycle restaged the orchestrator's own config
+    assert eng.reconfigurations == 2 and eng.config == dep.orch.current
+
+
 def test_transport_compression_accounting():
     b, params = _bundle_params("llama3-8b")
     L = len(b.model_graph())
