@@ -148,8 +148,8 @@ def main(argv=None) -> dict:
         "wire_MB": round(stats.wire_bytes / 1e6, 2),
         "compression_ratio": round(stats.compression_ratio, 2),
         "final_split": str(engine.config.boundaries),
-        # the served path's counters: a segment traced again on every call
-        # shows as as many traces as calls
+        # the served path's counters: a segment is traced once per prompt
+        # shape, not once per request
         "segment_calls": engine.stats.segment_calls,
         "segment_traces": engine.stats.segment_traces,
         "transfers": stats.transfers,

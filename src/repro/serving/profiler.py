@@ -109,16 +109,16 @@ class SegmentProfiler:
         wire_tok = {j: w / n_tok
                     for j, w in self.transport.stats.per_boundary.items()}
 
-        # timed per-segment passes (jitted; warmup covers compile)
+        # timed per-segment passes (each segment is one jitted program;
+        # warmup covers its compile)
         times = []
         for seg, xin in zip(chain.segments, inputs):
-            fn = jax.jit(seg.runner.__call__)
             for _ in range(self.warmup):
-                jax.block_until_ready(fn(seg.params, xin))
+                jax.block_until_ready(seg(xin))
             samples = []
             for _ in range(self.reps):
                 t0 = time.perf_counter()
-                jax.block_until_ready(fn(seg.params, xin))
+                jax.block_until_ready(seg(xin))
                 samples.append(time.perf_counter() - t0)
             times.append(float(np.median(samples)))
 

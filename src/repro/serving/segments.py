@@ -10,7 +10,9 @@ WHERE layers run, never WHAT they compute.
 
 from __future__ import annotations
 
+import contextvars
 import dataclasses
+import functools
 from dataclasses import dataclass
 from typing import Any
 
@@ -33,7 +35,10 @@ class ServingStats:
     ``segment_calls`` the segments run, and ``segment_traces`` the times
     JAX traced a segment's scan body: that Python runs only while JAX
     traces, so the count is exact and costs nothing once a body is traced.
-    Griffin segments run layer by layer with no scan body and count none.
+    Each segment's program is traced once per (segment, input shape) in the
+    process and reused after that, across re-splits too, so a warm server
+    counts none.  The trace counts into the stats of the runner whose call
+    caused it.  Griffin segments have no scan body and count none.
     """
 
     requests: int = 0
@@ -41,8 +46,124 @@ class ServingStats:
     segment_traces: int = 0
 
 
+# the stats that a trace of a segment's scan body counts into: those of the
+# runner whose call is tracing it
+_TRACING_FOR: contextvars.ContextVar[ServingStats | None] = \
+    contextvars.ContextVar("segment_tracing_for", default=None)
+
+
+def _count_trace() -> None:
+    stats = _TRACING_FOR.get()
+    if stats is not None:
+        stats.segment_traces += 1
+
+
 def _tf_slice_blocks(params: Any, lo: int, hi: int) -> Any:
     return jax.tree_util.tree_map(lambda a: a[lo:hi], params["blocks"])
+
+
+def _segment_forward(family: str, cfg: Any, lo: int, hi: int, local: bool,
+                     n_units: int, params: Any, x: jax.Array) -> jax.Array:
+    """Graph units [lo, hi) of one architecture (see :class:`SegmentRunner`)."""
+    L = n_units - 2                      # number of blocks
+
+    if family == "transformer":
+        if lo == 0:
+            x = transformer.embed_tokens(params, cfg, x)
+            lo = 1
+        blo, bhi = lo - 1, min(hi - 1, L)
+        if bhi > blo:
+            windows = jnp.asarray(cfg.windows())
+            moe = cfg.moe
+            n_lead = moe.first_dense_layers if moe else 0
+            for i in range(blo, min(bhi, n_lead)):
+                dense_cfg = dataclasses.replace(
+                    cfg, moe=None, d_ff=moe.dense_d_ff or cfg.d_ff)
+                li = i - blo if local else i
+                x = transformer.block_forward(
+                    x, params["lead_blocks"][li], dense_cfg, window=0)
+            slo, shi = max(blo - n_lead, 0), bhi - n_lead
+            if shi > slo:
+                sub = (params["blocks"] if local
+                       else _tf_slice_blocks(params, slo, shi))
+
+                def body(h, inputs):
+                    _count_trace()
+                    lp, w = inputs
+                    return transformer.block_forward(h, lp, cfg, window=w), None
+
+                x, _ = jax.lax.scan(
+                    body, x, (sub, windows[n_lead + slo:n_lead + shi]))
+        if hi == L + 2:
+            x = transformer.apply_norm(x, params["final_norm"], cfg.norm)
+            return transformer.logits_fn(params, cfg, x)
+        return x
+
+    if family == "mamba2":
+        if lo == 0:
+            x = mamba2.embed_tokens(params, cfg, x)
+            lo = 1
+        blo, bhi = lo - 1, min(hi - 1, L)
+        if bhi > blo:
+            sub = (params["blocks"] if local
+                   else _tf_slice_blocks(params, blo, bhi))
+
+            def body(h, lp):
+                _count_trace()
+                return mamba2.block_forward(h, lp, cfg), None
+
+            x, _ = jax.lax.scan(body, x, sub)
+        if hi == L + 2:
+            x = mamba2.apply_norm(x, params["final_norm"], cfg.norm)
+            return mamba2.logits_fn(params, cfg, x)
+        return x
+
+    if family == "griffin":
+        if lo == 0:
+            x = griffin.embed_tokens(params, cfg, x)
+            lo = 1
+        blo, bhi = lo - 1, min(hi - 1, L)
+        kinds = cfg.layer_kinds()
+        glen = len(cfg.pattern)
+        n_groups = cfg.n_layers // glen
+        for li in range(blo, bhi):
+            if li < n_groups * glen:
+                g, i = divmod(li, glen)
+                gp = jax.tree_util.tree_map(
+                    lambda a, g=g: a[g], params["groups"])
+                tm, mp = gp[f"t{i}"], gp[f"m{i}"]
+            else:
+                tl = params["tail"][li - n_groups * glen]
+                tm, mp = tl["t"], tl["m"]
+            if kinds[li] == "rec":
+                x = griffin.rec_forward(x, tm, cfg)
+            else:
+                x = griffin.attn_forward(x, tm, cfg)
+            # round at every layer boundary as a cut would, so that where
+            # the split falls never changes what the layers compute
+            x = jax.lax.optimization_barrier(griffin.mlp_forward(x, mp, cfg))
+        if hi == L + 2:
+            x = griffin.apply_norm(x, params["final_norm"], cfg.norm)
+            return griffin.logits_fn(params, cfg, x)
+        return x
+
+    raise ValueError(family)
+
+
+@functools.cache
+def _segment_program(family: str, cfg: Any, lo: int, hi: int, local: bool,
+                     n_units: int):
+    """The compiled program of one segment, shared by every runner of the
+    same segment in the process: keyed on what its trace depends on, so a
+    rebuilt chain finds the programs of a split it has run before.  The
+    params are an argument, never a constant of the program."""
+
+    def segment(params, x):
+        return _segment_forward(family, cfg, lo, hi, local, n_units, params, x)
+
+    # the device trace names the program jit_segment_<lo>_<hi>
+    segment.__name__ = f"segment_{lo}_{hi}"
+    return jax.jit(segment)
 
 
 @dataclass
@@ -56,6 +177,10 @@ class SegmentRunner:
     pre-sliced to this segment, so they are consumed whole.  Layer-position
     effects (attention windows, griffin's layer-kind pattern) always use
     global positions in both modes.
+
+    The segment runs as one jitted program (embed, blocks, final norm and
+    head, as the range holds them), traced once per input shape and shared
+    with every other runner of the same segment.
     """
 
     bundle: ModelBundle
@@ -63,6 +188,12 @@ class SegmentRunner:
     hi: int
     local: bool = False
     stats: ServingStats = dataclasses.field(default_factory=ServingStats)
+
+    def __post_init__(self) -> None:
+        n = self.n_units
+        assert 0 <= self.lo < self.hi <= n
+        self._program = _segment_program(self.bundle.family, self.bundle.cfg,
+                                         self.lo, self.hi, self.local, n)
 
     @property
     def n_units(self) -> int:
@@ -73,92 +204,11 @@ class SegmentRunner:
 
         Returns boundary activations, or fp32 logits if hi == n_units.
         """
-        b = self.bundle
-        cfg = b.cfg
-        fam = b.family
-        L = self.n_units - 2                 # number of blocks
-        lo, hi = self.lo, self.hi
-        assert 0 <= lo < hi <= L + 2
-
-        if fam == "transformer":
-            if lo == 0:
-                x = transformer.embed_tokens(params, cfg, x)
-                lo = 1
-            blo, bhi = lo - 1, min(hi - 1, L)
-            if bhi > blo:
-                windows = jnp.asarray(cfg.windows())
-                moe = cfg.moe
-                n_lead = moe.first_dense_layers if moe else 0
-                for i in range(blo, min(bhi, n_lead)):
-                    dense_cfg = dataclasses.replace(
-                        cfg, moe=None, d_ff=moe.dense_d_ff or cfg.d_ff)
-                    li = i - blo if self.local else i
-                    x = transformer.block_forward(
-                        x, params["lead_blocks"][li], dense_cfg, window=0)
-                slo, shi = max(blo - n_lead, 0), bhi - n_lead
-                if shi > slo:
-                    sub = (params["blocks"] if self.local
-                           else _tf_slice_blocks(params, slo, shi))
-
-                    def body(h, inputs):
-                        self.stats.segment_traces += 1
-                        lp, w = inputs
-                        return transformer.block_forward(h, lp, cfg, window=w), None
-
-                    x, _ = jax.lax.scan(
-                        body, x, (sub, windows[n_lead + slo:n_lead + shi]))
-            if hi == L + 2:
-                x = transformer.apply_norm(x, params["final_norm"], cfg.norm)
-                return transformer.logits_fn(params, cfg, x)
-            return x
-
-        if fam == "mamba2":
-            if lo == 0:
-                x = mamba2.embed_tokens(params, cfg, x)
-                lo = 1
-            blo, bhi = lo - 1, min(hi - 1, L)
-            if bhi > blo:
-                sub = (params["blocks"] if self.local
-                       else _tf_slice_blocks(params, blo, bhi))
-
-                def body(h, lp):
-                    self.stats.segment_traces += 1
-                    return mamba2.block_forward(h, lp, cfg), None
-
-                x, _ = jax.lax.scan(body, x, sub)
-            if hi == L + 2:
-                x = mamba2.apply_norm(x, params["final_norm"], cfg.norm)
-                return mamba2.logits_fn(params, cfg, x)
-            return x
-
-        if fam == "griffin":
-            if lo == 0:
-                x = griffin.embed_tokens(params, cfg, x)
-                lo = 1
-            blo, bhi = lo - 1, min(hi - 1, L)
-            kinds = cfg.layer_kinds()
-            glen = len(cfg.pattern)
-            n_groups = cfg.n_layers // glen
-            for li in range(blo, bhi):
-                if li < n_groups * glen:
-                    g, i = divmod(li, glen)
-                    gp = jax.tree_util.tree_map(
-                        lambda a, g=g: a[g], params["groups"])
-                    tm, mp = gp[f"t{i}"], gp[f"m{i}"]
-                else:
-                    tl = params["tail"][li - n_groups * glen]
-                    tm, mp = tl["t"], tl["m"]
-                if kinds[li] == "rec":
-                    x = griffin.rec_forward(x, tm, cfg)
-                else:
-                    x = griffin.attn_forward(x, tm, cfg)
-                x = griffin.mlp_forward(x, mp, cfg)
-            if hi == L + 2:
-                x = griffin.apply_norm(x, params["final_norm"], cfg.norm)
-                return griffin.logits_fn(params, cfg, x)
-            return x
-
-        raise ValueError(fam)
+        token = _TRACING_FOR.set(self.stats)
+        try:
+            return self._program(params, x)
+        finally:
+            _TRACING_FOR.reset(token)
 
 
 def split_params(bundle: ModelBundle, params: Any,

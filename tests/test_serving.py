@@ -142,9 +142,13 @@ def test_serve_counters_count_the_served_path_and_survive_a_resplit():
     """``Deployment.serve`` counts requests, segment calls and the traces of
     the segments' scan bodies into the engine's stats, the transport its
     crossings, and the orchestrator each decision by kind; a re-split keeps
-    every count."""
+    every count.  A segment is traced once per shape: a second request of
+    the same shape traces nothing, a re-split only its new segments."""
     from repro.launch.serve import deploy
+    from repro.serving import segments
 
+    # programs another test of this process compiled would hide the traces
+    segments._segment_program.cache_clear()
     dep = deploy("stablelm-3b", reduced=True, compress=True, interpret=True,
                  prompt_len=8)
     eng, vocab = dep.engine, dep.bundle.cfg.vocab
@@ -159,6 +163,8 @@ def test_serve_counters_count_the_served_path_and_survive_a_resplit():
     assert seen[0] == (0, 0, 0, 0, 0)
     for i in range(4):
         if i == 2:      # re-split between requests: the chain is rebuilt
+            old = set(zip(eng.config.boundaries, eng.config.boundaries[1:]))
+            new = {(0, 2), (2, 3), (3, L)} - old
             eng.apply_config(PartitionConfig(eng.config.version + 1,
                                              (0, 2, 3, L), (0, 3, 0)))
         toks = jax.random.randint(jax.random.PRNGKey(i), (1, 8), 0, vocab)
@@ -170,9 +176,35 @@ def test_serve_counters_count_the_served_path_and_survive_a_resplit():
     assert requests == 4 and calls == 3 * requests
     assert transfers == 2 * requests and decisions == requests
     assert 1 <= traces <= calls
-    assert traces >= 2 * requests     # every call traces its scan again
+    traced = [after[2] - before[2] for before, after in zip(seen, seen[1:])]
+    assert traced[1] == 0             # the same shape again: nothing traced
+    assert traced[2] <= len(new)      # the re-split traced its new segments
     # the next request's cycle restaged the orchestrator's own config
     assert eng.reconfigurations == 2 and eng.config == dep.orch.current
+
+
+def test_compiled_segments_outlive_the_chain_across_resplits():
+    """Each segment's compiled program is kept per segment, not per chain:
+    going back to a split served before traces nothing, and answers with
+    the same logits."""
+    from repro.launch.serve import deploy
+
+    dep = deploy("stablelm-3b", reduced=True, compress=True, interpret=True,
+                 prompt_len=8)
+    eng, vocab = dep.engine, dep.bundle.cfg.vocab
+    L = len(dep.bundle.model_graph())
+    toks = jax.random.randint(jax.random.PRNGKey(3), (1, 8), 0, vocab)
+    split_a, split_b = (0, 2, L), (0, 1, 3, L)
+    eng.apply_config(PartitionConfig(eng.config.version + 1, split_a, (0, 3)))
+    first = np.asarray(eng.infer_logits(toks))
+    eng.apply_config(PartitionConfig(eng.config.version + 1, split_b,
+                                     (0, 3, 0)))
+    eng.infer_logits(toks)
+    eng.apply_config(PartitionConfig(eng.config.version + 1, split_a, (0, 3)))
+    traces = eng.stats.segment_traces
+    again = np.asarray(eng.infer_logits(toks))
+    assert eng.stats.segment_traces == traces
+    np.testing.assert_array_equal(again, first)
 
 
 def test_transport_compression_accounting():
