@@ -2,6 +2,8 @@
 
 from __future__ import annotations
 
+import math
+from dataclasses import dataclass
 from typing import Any
 
 import jax
@@ -73,20 +75,80 @@ def rope_frequencies(head_dim: int, theta: float = 10_000.0) -> jax.Array:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude correction ``0.1 * mscale * ln(factor) + 1``."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+@dataclass(frozen=True)
+class YaRN:
+    """YaRN rotary scaling, as a published config's ``rope_scaling`` of type
+    ``yarn`` gives it (DeepSeek-V2's ``DeepseekV2YarnRotaryEmbedding``).
+
+    Frequencies of the lowest indices (fast rotations, above ``beta_fast``
+    turns over the original context) stay as they are, those of the highest
+    (below ``beta_slow`` turns) are divided by ``factor``, and a linear ramp
+    blends the two in between.  Rotated features are multiplied by
+    ``rope_mscale``; the attention that uses them multiplies its softmax
+    scale by ``softmax_mscale``.
+    """
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    def _correction_dim(self, turns: float, dim: int, theta: float) -> float:
+        return (dim * math.log(self.original_max_position / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    def ramp(self, dim: int, theta: float) -> tuple[int, int]:
+        """First and last pair index of the blend."""
+        lo = math.floor(self._correction_dim(self.beta_fast, dim, theta))
+        hi = math.ceil(self._correction_dim(self.beta_slow, dim, theta))
+        return max(lo, 0), min(hi, dim - 1)
+
+    def frequencies(self, dim: int, theta: float) -> jax.Array:
+        extra = rope_frequencies(dim, theta)
+        lo, hi = self.ramp(dim, theta)
+        span = (hi - lo) if hi > lo else 0.001
+        interp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - lo) / span,
+                          0.0, 1.0)
+        return extra / self.factor * interp + extra * (1.0 - interp)
+
+    @property
+    def rope_mscale(self) -> float:
+        return (yarn_mscale(self.factor, self.mscale)
+                / yarn_mscale(self.factor, self.mscale_all_dim))
+
+    @property
+    def softmax_mscale(self) -> float:
+        if not self.mscale_all_dim:
+            return 1.0
+        return yarn_mscale(self.factor, self.mscale_all_dim) ** 2
+
+
 def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10_000.0,
-               rope_dim: int | None = None) -> jax.Array:
+               rope_dim: int | None = None, yarn: YaRN | None = None
+               ) -> jax.Array:
     """x: [..., S, H, hd]; positions: broadcastable to [..., S].
 
     ``rope_dim``: rotate only the first ``rope_dim`` features (partial RoPE).
+    ``yarn``: YaRN's blended frequencies and magnitude (see :class:`YaRN`).
     Uses the interleaved-pairs convention throughout the repo.
     """
     hd = x.shape[-1]
     rd = hd if rope_dim is None else rope_dim
     xr, xp = x[..., :rd], x[..., rd:]
-    freqs = rope_frequencies(rd, theta)                       # [rd/2]
+    freqs = (rope_frequencies(rd, theta) if yarn is None
+             else yarn.frequencies(rd, theta))                # [rd/2]
     ang = positions[..., None].astype(jnp.float32) * freqs    # [..., S, rd/2]
     cos = jnp.cos(ang)[..., None, :]                          # [..., S, 1, rd/2]
     sin = jnp.sin(ang)[..., None, :]
+    if yarn is not None and yarn.rope_mscale != 1.0:
+        cos, sin = cos * yarn.rope_mscale, sin * yarn.rope_mscale
     x1 = xr[..., 0::2].astype(jnp.float32)
     x2 = xr[..., 1::2].astype(jnp.float32)
     o1 = x1 * cos - x2 * sin

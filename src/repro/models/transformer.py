@@ -3,7 +3,7 @@
 One config dataclass + pure functions.  Feature axes (all combinable):
   * GQA / MQA / MHA via ``n_kv``
   * MLA (DeepSeek-V2) latent KV compression + decoupled RoPE
-  * MoE (token-choice top-k, capacity-bounded, gather-based dispatch)
+  * MoE (token-choice top-k, dropless: a grouped matmul over the experts)
   * alternating local/global attention (per-layer window schedule)
   * attention & final logit soft-capping (Gemma-2)
   * parallel attention+FFN blocks (Command-R), QK-norm (Qwen3),
@@ -31,6 +31,7 @@ from .attention import chunked_attention
 from .common import (
     KeyGen,
     Params,
+    YaRN,
     activation,
     apply_norm,
     apply_rope,
@@ -51,7 +52,6 @@ class MoEConfig:
     num_shared: int = 0             # always-on shared experts (DeepSeek)
     first_dense_layers: int = 0     # leading dense layers (DeepSeek-V2)
     dense_d_ff: int = 0             # FFN width of those dense layers
-    capacity_factor: float = 1.25
     router_scale: bool = True       # normalize top-k gate weights to sum 1
 
 
@@ -61,6 +61,18 @@ class MLAConfig:
     rope_head_dim: int = 64
     nope_head_dim: int = 128
     v_head_dim: int = 128
+    rope_scaling: YaRN | None = None   # on the decoupled rotary key/query
+
+    def rope(self, x: jax.Array, positions: jax.Array,
+             theta: float) -> jax.Array:
+        return apply_rope(x, positions, theta, yarn=self.rope_scaling)
+
+    @property
+    def softmax_scale(self) -> float:
+        scale = (self.nope_head_dim + self.rope_head_dim) ** -0.5
+        if self.rope_scaling is not None:
+            scale *= self.rope_scaling.softmax_mscale
+        return scale
 
 
 @dataclass(frozen=True)
@@ -228,66 +240,53 @@ def init_params(cfg: TransformerConfig, key: jax.Array,
 
 
 # --------------------------------------------------------------------------- #
-# MoE: token-choice top-k with capacity, gather-based dispatch (no fake FLOPs)
+# MoE: token-choice top-k, dropless (every token reaches all k of its experts)
 # --------------------------------------------------------------------------- #
+def _ffn(x: jax.Array, p: Params, cfg: TransformerConfig, mm) -> jax.Array:
+    """An FFN on rows ``x`` [N, d] whose products ``mm(a, w)`` compute."""
+    h = activation(mm(x, p["wi"]), cfg.act)
+    if cfg.glu:
+        h = h * mm(x, p["wg"])
+    return mm(h, p["wo"])
+
+
 def moe_ffn(x: jax.Array, p: Params, cfg: TransformerConfig) -> jax.Array:
-    """x: [B, S, d] -> [B, S, d]."""
+    """x: [B, S, d] -> [B, S, d].
+
+    Each token's top-k (token, expert) pairs are sorted by expert, the routed
+    experts run as one grouped matmul with ragged group sizes, and the
+    outputs are summed back per token by gate weight in float32; no capacity,
+    so no token ever loses an expert.  The shared experts see every token.
+    """
     moe = cfg.moe
     b, s, d = x.shape
-    t = b * s
+    t, k = b * s, moe.top_k
     xf = x.reshape(t, d)
-    logits = (xf.astype(jnp.float32) @ p["router"].astype(jnp.float32))
-    gates = jax.nn.softmax(logits, axis=-1)                       # [T, E]
-    topv, tope = jax.lax.top_k(gates, moe.top_k)                  # [T, k]
-    if moe.router_scale:
-        topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
-
-    e_flat = tope.reshape(-1)                                     # [T*k]
-    w_flat = topv.reshape(-1)
-    tok_flat = jnp.repeat(jnp.arange(t), moe.top_k)
-
-    cap = int(np.ceil(t * moe.top_k / moe.num_experts * moe.capacity_factor))
-    cap = max(cap, 4)
-    # stable sort by expert; rank within expert = slot
-    order = jnp.argsort(e_flat, stable=True)
-    e_sorted = e_flat[order]
-    tok_sorted = tok_flat[order]
-    w_sorted = w_flat[order]
-    # slot index inside each expert group
-    counts = jnp.bincount(e_flat, length=moe.num_experts)
-    offsets = jnp.concatenate([jnp.zeros(1, counts.dtype), jnp.cumsum(counts)[:-1]])
-    slot = jnp.arange(t * moe.top_k) - offsets[e_sorted]
-    # overflow tokens land in a dump column (cap) that is sliced off, so they
-    # can never clobber a kept token's slot
-    slot_c = jnp.minimum(slot, cap)
-
-    idx = jnp.zeros((moe.num_experts, cap + 1), jnp.int32)
-    idx = idx.at[e_sorted, slot_c].set(tok_sorted.astype(jnp.int32))
-    wmat = jnp.zeros((moe.num_experts, cap + 1), jnp.float32)
-    wmat = wmat.at[e_sorted, slot_c].set(w_sorted)
-    idx, wmat = idx[:, :cap], wmat[:, :cap]
-
-    xin = xf[idx]                                                 # [E, C, d]
-    we = p["experts"]
-    hgate = jnp.einsum("ecd,edf->ecf", xin, we["wi"].astype(xin.dtype))
-    if cfg.glu:
-        hlin = jnp.einsum("ecd,edf->ecf", xin, we["wg"].astype(xin.dtype))
-        h = activation(hgate, cfg.act) * hlin
-    else:
-        h = activation(hgate, cfg.act)
-    eout = jnp.einsum("ecf,efd->ecd", h, we["wo"].astype(h.dtype))  # [E, C, d]
-    eout = eout * wmat[..., None].astype(eout.dtype)
-
-    out = jnp.zeros((t, d), eout.dtype).at[idx.reshape(-1)].add(
-        eout.reshape(-1, d))
+    with jax.named_scope("moe_router"):
+        logits = xf.astype(jnp.float32) @ p["router"].astype(jnp.float32)
+        gates = jax.nn.softmax(logits, axis=-1)                   # [T, E]
+        topv, tope = jax.lax.top_k(gates, k)                      # [T, k]
+        if moe.router_scale:
+            topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
+    with jax.named_scope("moe_dispatch"):
+        e_flat = tope.reshape(-1)                                 # [T*k]
+        order = jnp.argsort(e_flat, stable=True)
+        sizes = jnp.bincount(e_flat, length=moe.num_experts).astype(jnp.int32)
+        xs = xf[order // k]                                       # [T*k, d]
+    with jax.named_scope("moe_experts"):
+        # rows [sum(sizes[:e]), sum(sizes[:e+1])) go through expert e: one
+        # grouped matmul a projection, a grouped-matmul kernel on the TPU,
+        # so the work is that of the routed rows only
+        ys = _ffn(xs, p["experts"], cfg, lambda a, w: jax.lax.ragged_dot(
+            a, w.astype(a.dtype), sizes))                         # [T*k, d]
+    with jax.named_scope("moe_combine"):
+        back = jnp.zeros_like(order).at[order].set(jnp.arange(t * k))
+        pairs = ys[back].reshape(t, k, d).astype(jnp.float32)
+        out = jnp.sum(pairs * topv[..., None], axis=1)
     if moe.num_shared:
-        sh = p["shared"]
-        hg = xf @ sh["wi"].astype(xf.dtype)
-        if cfg.glu:
-            h2 = activation(hg, cfg.act) * (xf @ sh["wg"].astype(xf.dtype))
-        else:
-            h2 = activation(hg, cfg.act)
-        out = out + h2 @ sh["wo"].astype(h2.dtype)
+        with jax.named_scope("moe_shared"):
+            out = out + _ffn(xf, p["shared"], cfg,
+                             lambda a, w: a @ w.astype(a.dtype)).astype(jnp.float32)
     return out.reshape(b, s, d).astype(x.dtype)
 
 
@@ -311,6 +310,33 @@ def _qk_normed(q, k, p, cfg):
     return q, k
 
 
+@jax.named_scope("mla")
+def _mla_forward(x, p, cfg: TransformerConfig, *, window, q_offset, kv_block):
+    """Latent attention (DeepSeek-V2): keys and values decompressed from a
+    normed latent, a decoupled rotary key shared by every head."""
+    b, s, d = x.shape
+    m = cfg.mla
+    q = jnp.einsum("bsd,dhq->bshq", x, p["wq"].astype(x.dtype))
+    q_nope, q_rope = q[..., : m.nope_head_dim], q[..., m.nope_head_dim:]
+    ckv = apply_norm(
+        jnp.einsum("bsd,dl->bsl", x, p["wdkv"].astype(x.dtype)),
+        p["kv_ln"], "rms")
+    k_rope = jnp.einsum("bsd,dr->bsr", x, p["wkr"].astype(x.dtype))
+    pos = q_offset + jnp.arange(s)
+    q_rope = m.rope(q_rope, pos, cfg.rope_theta)
+    k_rope = m.rope(k_rope[:, :, None, :], pos, cfg.rope_theta)
+    k_nope = jnp.einsum("bsl,lhq->bshq", ckv, p["wuk"].astype(x.dtype))
+    v = jnp.einsum("bsl,lhv->bshv", ckv, p["wuv"].astype(x.dtype))
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_rope, (b, s, cfg.n_heads, m.rope_head_dim))],
+        axis=-1)
+    q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
+    o = chunked_attention(q_full, k, v, causal=True, window=window,
+                          logit_cap=cfg.attn_softcap, q_offset=q_offset,
+                          kv_block=kv_block, scale=m.softmax_scale)
+    return jnp.einsum("bshv,hvd->bsd", o, p["wo"].astype(o.dtype))
+
+
 def attn_forward(
     x: jax.Array, p: Params, cfg: TransformerConfig, *,
     window: jax.Array | int, q_offset=0, kv_block: int = 1024,
@@ -318,27 +344,8 @@ def attn_forward(
     """Full-sequence attention (train / prefill compute). x: [B,S,d]."""
     b, s, d = x.shape
     if cfg.mla is not None:
-        m = cfg.mla
-        q = jnp.einsum("bsd,dhq->bshq", x, p["wq"].astype(x.dtype))
-        q_nope, q_rope = q[..., : m.nope_head_dim], q[..., m.nope_head_dim:]
-        ckv = apply_norm(
-            jnp.einsum("bsd,dl->bsl", x, p["wdkv"].astype(x.dtype)),
-            p["kv_ln"], "rms")
-        k_rope = jnp.einsum("bsd,dr->bsr", x, p["wkr"].astype(x.dtype))
-        pos = q_offset + jnp.arange(s)
-        q_rope = apply_rope(q_rope, pos, cfg.rope_theta)
-        k_rope = apply_rope(k_rope[:, :, None, :], pos, cfg.rope_theta)
-        k_nope = jnp.einsum("bsl,lhq->bshq", ckv, p["wuk"].astype(x.dtype))
-        v = jnp.einsum("bsl,lhv->bshv", ckv, p["wuv"].astype(x.dtype))
-        k = jnp.concatenate(
-            [k_nope, jnp.broadcast_to(k_rope, (b, s, cfg.n_heads, m.rope_head_dim))],
-            axis=-1)
-        q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
-        scale = (m.nope_head_dim + m.rope_head_dim) ** -0.5
-        o = chunked_attention(q_full, k, v, causal=True, window=window,
-                              logit_cap=cfg.attn_softcap, q_offset=q_offset,
-                              kv_block=kv_block, scale=scale)
-        return jnp.einsum("bshv,hvd->bsd", o, p["wo"].astype(o.dtype))
+        return _mla_forward(x, p, cfg, window=window, q_offset=q_offset,
+                            kv_block=kv_block)
 
     q = constrain(jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(x.dtype)),
                   "heads")

@@ -78,7 +78,7 @@ def _project_kv(x, p, cfg: TransformerConfig, pos):
             jnp.einsum("bsd,dl->bsl", x, p["wdkv"].astype(x.dtype)),
             p["kv_ln"], "rms")
         kr = jnp.einsum("bsd,dr->bsr", x, p["wkr"].astype(x.dtype))
-        kr = apply_rope(kr[:, :, None, :], pos, cfg.rope_theta)[:, :, 0, :]
+        kr = m.rope(kr[:, :, None, :], pos, cfg.rope_theta)[:, :, 0, :]
         return {"ckv": ckv, "kr": kr}
     k = jnp.einsum("bsd,dgk->bsgk", x, p["wk"].astype(x.dtype))
     v = jnp.einsum("bsd,dgk->bsgk", x, p["wv"].astype(x.dtype))
@@ -186,12 +186,12 @@ def _decode_attn_mla(x, p, cfg: TransformerConfig, layer_cache, pos, window):
     q = jnp.einsum("bsd,dhq->bshq", x, p["wq"].astype(x.dtype))[:, 0]  # [B,h,qk]
     q_nope, q_rope = q[..., : m.nope_head_dim], q[..., m.nope_head_dim:]
     posv = pos + jnp.zeros((1,), jnp.int32)
-    q_rope = apply_rope(q_rope[:, None], posv, cfg.rope_theta)[:, 0]
+    q_rope = m.rope(q_rope[:, None], posv, cfg.rope_theta)[:, 0]
 
     ckv_new = apply_norm(
         jnp.einsum("bsd,dl->bsl", x, p["wdkv"].astype(x.dtype)), p["kv_ln"], "rms")
     kr_new = jnp.einsum("bsd,dr->bsr", x, p["wkr"].astype(x.dtype))
-    kr_new = apply_rope(kr_new[:, :, None, :], posv, cfg.rope_theta)[:, :, 0, :]
+    kr_new = m.rope(kr_new[:, :, None, :], posv, cfg.rope_theta)[:, :, 0, :]
     ckv = jax.lax.dynamic_update_slice_in_dim(
         layer_cache["ckv"], ckv_new.astype(layer_cache["ckv"].dtype), pos, axis=1)
     kr = jax.lax.dynamic_update_slice_in_dim(
@@ -199,7 +199,7 @@ def _decode_attn_mla(x, p, cfg: TransformerConfig, layer_cache, pos, window):
 
     # absorb W_uk into q:  q_lat[b,h,l] = q_nope[b,h,n] · wuk[l,h,n]
     q_lat = jnp.einsum("bhn,lhn->bhl", q_nope, p["wuk"].astype(q_nope.dtype))
-    scale = (m.nope_head_dim + m.rope_head_dim) ** -0.5
+    scale = m.softmax_scale
     # bf16 operands + f32 accumulation; no f32 shadow of the latent cache
     s_nope = jnp.einsum("bhl,bsl->bhs", q_lat.astype(ckv.dtype), ckv,
                         preferred_element_type=jnp.float32)

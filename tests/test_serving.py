@@ -1,8 +1,6 @@
 """Serving semantics: split == monolith; prefill+decode == full forward;
 transport compression accounting; wave batching."""
 
-import dataclasses
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,7 +8,6 @@ import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.configs import ALL_ARCHS, get_bundle
-from repro.models.api import bundle_for
 from repro.serving import (
     ActivationTransport,
     Request,
@@ -26,10 +23,6 @@ _KEY = jax.random.PRNGKey(7)
 
 def _bundle_params(arch):
     b = get_bundle(arch, reduced=True)
-    if getattr(b.cfg, "moe", None) is not None:
-        # generous capacity so routing is identical across split points
-        b = bundle_for(arch, dataclasses.replace(
-            b.cfg, moe=dataclasses.replace(b.cfg.moe, capacity_factor=64.0)))
     params = b.init(_KEY, jnp.float32)
     return b, params
 

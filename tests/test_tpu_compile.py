@@ -82,3 +82,32 @@ def test_resident_price_compiles_for_v5e(one_chip):
                                           1e3, 0.05))
         compiled = price.lower(*rows, *state).compile()
     assert compiled.memory_analysis() is not None
+
+
+def test_moe_layer_compiles_to_a_grouped_matmul_for_v5e(one_chip):
+    """DeepSeek-V2-Lite's expert layer at its published widths on a
+    2,048-row prompt: the routed experts compile to the grouped-matmul
+    kernel, and the compiled FLOPs are those of the routed rows (6 of 64
+    experts a token), not of a dense product over all 64."""
+    from repro.models.transformer import moe_ffn
+
+    cfg = get("deepseek-v2-lite-16b")
+    moe, d, t = cfg.moe, cfg.d_model, 2_048
+
+    def sds(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def ffn(width, *lead):
+        return {"wi": sds((*lead, d, width)), "wg": sds((*lead, d, width)),
+                "wo": sds((*lead, width, d))}
+
+    p = {"router": sds((d, moe.num_experts)),
+         "experts": ffn(moe.d_expert, moe.num_experts),
+         "shared": ffn(moe.d_expert * moe.num_shared)}
+    compiled = jax.jit(moe_ffn, static_argnums=2).lower(
+        sds((1, t, d)), p, cfg).compile()
+    assert "ragged-dot" in compiled.as_text()
+    routed = 2 * t * d * (moe.num_experts + 3 * moe.d_expert * (
+        moe.top_k + moe.num_shared))
+    flops = compiled.cost_analysis()["flops"]
+    assert 0.95 * routed < flops < 1.1 * routed, (flops, routed)
