@@ -152,6 +152,7 @@ def main(argv=None) -> dict:
         # shape, not once per request
         "segment_calls": engine.stats.segment_calls,
         "segment_traces": engine.stats.segment_traces,
+        "attention_kernel_layers": engine.stats.attention_kernel_layers,
         "transfers": stats.transfers,
         "decisions": dict(dep.orch.decision_counts),
     }

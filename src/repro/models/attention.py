@@ -1,28 +1,50 @@
-"""Attention cores in pure JAX (XLA path).
+"""Attention cores.
 
-Two entry points:
+Entry points:
 
+* :func:`causal_attention` — the full-sequence causal attention of training
+  and prefill (``transformer.attn_forward``, dense and latent).  It runs the
+  block-sparse splash flash-attention kernel (Pallas, shipped with JAX) where
+  :func:`uses_flash_kernel` holds — on the TPU, global attention from
+  position 0, a query length that is a multiple of 128 and at least 1,024 —
+  and :func:`chunked_attention` everywhere else (the CPU, short prompts,
+  sliding windows, prefill chunks at an offset).
 * :func:`chunked_attention` — flash-style online-softmax attention scanning
-  over KV blocks.  Memory is O(S · kv_block) instead of O(S²), so 32k-token
-  prefill lowers/compiles without materializing the score matrix.  The math is
-  IDENTICAL to the Pallas kernel in ``repro.kernels.flash_attention`` (which
-  is the TPU production path); this function is what the dry-run lowers, so
-  the roofline HLO stays representative of the kernel's FLOPs/bytes.
+  over KV blocks in XLA.  Memory is O(S · kv_block) instead of O(S²), so
+  32k-token prefill lowers/compiles without materializing the score matrix;
+  it masks causal pairs after the products instead of skipping them.
+* :func:`splash_attention` — the kernel path on its own (``interpret=True``
+  runs it on the CPU, for tests).
 * :func:`decode_attention` — one-token GQA attention against a KV cache,
   fp32 accumulation, position masking.
 
-Both support causal masks, sliding windows (Gemma-2 local layers), logit
-soft-capping, and grouped-query heads (any H/KV ratio, including MQA kv=1).
+The full-sequence paths take causal masks, logit soft-capping, grouped-query
+heads (any H/KV ratio, including MQA kv=1) and a value head size of their own
+(MLA); the XLA paths also take sliding windows (Gemma-2 local layers).
 """
 
 from __future__ import annotations
 
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
+from ..distributed.context import current_mesh
 from .common import softcap as _softcap
 
 NEG_INF = -2.0e38
+
+# the kernel's block: 512 rows of q and of kv was the fastest of twelve
+# (block_q, block_kv, block_kv_compute) choices at 512, 1,024 and 2,048 rows,
+# for dense and latent heads alike (TPU v5e, PERF.md); a length it does not
+# divide takes the largest 128-row multiple that does
+_SPLASH_BLOCK = 512
+# below 1,024 rows a layer's attention ran faster through the XLA scan,
+# then a single fused block (TPU v5e, PERF.md)
+_SPLASH_MIN_ROWS = 1024
 
 
 def _gqa_reshape(q: jax.Array, n_kv: int):
@@ -106,6 +128,83 @@ def chunked_attention(
     )
     out = acc / jnp.maximum(l[..., None], 1e-30)
     return out.reshape(b, sq, h, hd_v).astype(q.dtype)
+
+
+def uses_flash_kernel(rows: int, window, q_offset) -> bool:
+    """Whether :func:`causal_attention` runs the splash kernel for a query
+    of ``rows`` rows with this ``window`` and ``q_offset``.
+
+    It does on the TPU for global attention from position 0, both known
+    while tracing (a Python ``0``; a traced window or offset is not), over
+    a multiple of 128 rows (the kernel's blocks are) and at least 1,024,
+    in a program of one device: under an activation mesh the compiler
+    would have to partition the kernel, which it cannot.
+    """
+    return (jax.default_backend() == "tpu" and current_mesh() is None
+            and isinstance(window, int) and window == 0
+            and isinstance(q_offset, int) and q_offset == 0
+            and rows % 128 == 0 and rows >= _SPLASH_MIN_ROWS)
+
+
+@functools.cache
+def _splash_kernel(heads: int, rows: int, logit_cap: float, interpret: bool):
+    """The causal splash kernel over ``heads`` heads of ``rows`` rows."""
+    blk = math.gcd(rows, _SPLASH_BLOCK)
+    blocks = splash.BlockSizes(
+        block_q=blk, block_kv=blk, block_kv_compute=blk,
+        # the backward pass (a differentiated caller) at the same blocks
+        block_q_dkv=blk, block_kv_dkv=blk, block_kv_dkv_compute=blk,
+        block_q_dq=blk, block_kv_dq=blk)
+    mask = splash.MultiHeadMask([splash.CausalMask((rows, rows))] * heads)
+    # the kernel holds its block tables as arrays: make them concrete even
+    # when the first caller is tracing, so every later program can use them
+    with jax.ensure_compile_time_eval():
+        return splash.make_splash_mha(
+            mask, block_sizes=blocks, head_shards=1, q_seq_shards=1,
+            attn_logits_soft_cap=logit_cap or None, interpret=interpret)
+
+
+def splash_attention(
+    q: jax.Array,                # [B, S, H, hd]
+    k: jax.Array,                # [B, S, KV, hd]
+    v: jax.Array,                # [B, S, KV, hd_v]
+    *,
+    logit_cap: float = 0.0,
+    scale: float | None = None,
+    interpret: bool = False,
+) -> jax.Array:
+    """Causal attention from position 0 through the splash kernel, which
+    skips the blocks wholly above the diagonal and keeps scores in VMEM.
+    Products in the operands' dtype with f32 softmax and accumulation, as
+    :func:`chunked_attention`.  Returns [B, S, H, hd_v]."""
+    b, s, h, hd = q.shape
+    sc = (hd ** -0.5) if scale is None else scale
+    kernel = _splash_kernel(h, s, float(logit_cap), interpret)
+    # the kernel applies no scale: scale q in its storage dtype, as
+    # chunked_attention does, and lay each head's rows out contiguously
+    qh = jnp.swapaxes(q * jnp.asarray(sc, q.dtype), 1, 2)    # [B,H,S,hd]
+    out = jax.vmap(kernel)(qh, jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2))
+    return jnp.swapaxes(out, 1, 2)
+
+
+def causal_attention(
+    q: jax.Array,                # [B, Sq, H, hd]
+    k: jax.Array,                # [B, Sk, KV, hd]
+    v: jax.Array,                # [B, Sk, KV, hd_v]
+    *,
+    window: int | jax.Array = 0,
+    logit_cap: float = 0.0,
+    q_offset: int | jax.Array = 0,
+    kv_block: int = 1024,
+    scale: float | None = None,
+) -> jax.Array:
+    """Causal attention of a full sequence: the splash kernel where
+    :func:`uses_flash_kernel` holds, :func:`chunked_attention` otherwise."""
+    if uses_flash_kernel(q.shape[1], window, q_offset):
+        return splash_attention(q, k, v, logit_cap=logit_cap, scale=scale)
+    return chunked_attention(q, k, v, causal=True, window=window,
+                             logit_cap=logit_cap, q_offset=q_offset,
+                             kv_block=kv_block, scale=scale)
 
 
 def decode_attention(

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..distributed.context import constrain
-from .attention import chunked_attention
+from .attention import causal_attention
 from .common import (
     KeyGen,
     Params,
@@ -331,9 +331,9 @@ def _mla_forward(x, p, cfg: TransformerConfig, *, window, q_offset, kv_block):
         [k_nope, jnp.broadcast_to(k_rope, (b, s, cfg.n_heads, m.rope_head_dim))],
         axis=-1)
     q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
-    o = chunked_attention(q_full, k, v, causal=True, window=window,
-                          logit_cap=cfg.attn_softcap, q_offset=q_offset,
-                          kv_block=kv_block, scale=m.softmax_scale)
+    o = causal_attention(q_full, k, v, window=window,
+                         logit_cap=cfg.attn_softcap, q_offset=q_offset,
+                         kv_block=kv_block, scale=m.softmax_scale)
     return jnp.einsum("bshv,hvd->bsd", o, p["wo"].astype(o.dtype))
 
 
@@ -358,9 +358,9 @@ def attn_forward(
     rd = int(cfg.hd * cfg.rope_frac) if cfg.rope_frac < 1.0 else None
     q = apply_rope(q, pos, cfg.rope_theta, rope_dim=rd)
     k = apply_rope(k, pos, cfg.rope_theta, rope_dim=rd)
-    o = chunked_attention(q, k, v, causal=True, window=window,
-                          logit_cap=cfg.attn_softcap, q_offset=q_offset,
-                          kv_block=kv_block, scale=cfg.attn_scale)
+    o = causal_attention(q, k, v, window=window,
+                         logit_cap=cfg.attn_softcap, q_offset=q_offset,
+                         kv_block=kv_block, scale=cfg.attn_scale)
     return constrain(jnp.einsum("bshk,hkd->bsd", o, p["wo"].astype(o.dtype)),
                      "hidden")
 

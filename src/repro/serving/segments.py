@@ -22,6 +22,7 @@ from jax.profiler import TraceAnnotation
 
 from ..models import griffin, mamba2, transformer
 from ..models.api import ModelBundle
+from ..models.attention import uses_flash_kernel
 
 __all__ = ["BoundSegment", "SegmentChain", "SegmentRunner", "ServingStats",
            "split_params", "run_chain"]
@@ -39,11 +40,16 @@ class ServingStats:
     process and reused after that, across re-splits too, so a warm server
     counts none.  The trace counts into the stats of the runner whose call
     caused it.  Griffin segments have no scan body and count none.
+    ``attention_kernel_layers`` counts the attention layers that ran
+    through the splash kernel, decided per segment call from its row count
+    by the predicate the program itself uses
+    (:func:`~repro.models.attention.uses_flash_kernel`).
     """
 
     requests: int = 0
     segment_calls: int = 0
     segment_traces: int = 0
+    attention_kernel_layers: int = 0
 
 
 # the stats that a trace of a segment's scan body counts into: those of the
@@ -62,6 +68,23 @@ def _tf_slice_blocks(params: Any, lo: int, hi: int) -> Any:
     return jax.tree_util.tree_map(lambda a: a[lo:hi], params["blocks"])
 
 
+def _static_window(cfg: Any) -> int | None:
+    """The attention window every scanned block gets, as a Python int, where
+    the schedule is uniform (so the program knows it while tracing); None
+    where blocks differ and the scan carries each one's window."""
+    w = cfg.windows()
+    return int(w[0]) if len(set(w.tolist())) == 1 else None
+
+
+def _tf_block_range(cfg: Any, lo: int, hi: int, n_units: int):
+    """Graph units [lo, hi) of a transformer as block indices: the lead
+    (unscanned) blocks [blo, min(bhi, n_lead)) and the scanned ones
+    [slo, shi) of ``params["blocks"]``."""
+    blo, bhi = max(lo, 1) - 1, min(hi - 1, n_units - 2)
+    n_lead = cfg.moe.first_dense_layers if cfg.moe else 0
+    return blo, bhi, n_lead, max(blo - n_lead, 0), bhi - n_lead
+
+
 def _segment_forward(family: str, cfg: Any, lo: int, hi: int, local: bool,
                      n_units: int, params: Any, x: jax.Array) -> jax.Array:
     """Graph units [lo, hi) of one architecture (see :class:`SegmentRunner`)."""
@@ -70,30 +93,28 @@ def _segment_forward(family: str, cfg: Any, lo: int, hi: int, local: bool,
     if family == "transformer":
         if lo == 0:
             x = transformer.embed_tokens(params, cfg, x)
-            lo = 1
-        blo, bhi = lo - 1, min(hi - 1, L)
-        if bhi > blo:
-            windows = jnp.asarray(cfg.windows())
-            moe = cfg.moe
-            n_lead = moe.first_dense_layers if moe else 0
-            for i in range(blo, min(bhi, n_lead)):
-                dense_cfg = dataclasses.replace(
-                    cfg, moe=None, d_ff=moe.dense_d_ff or cfg.d_ff)
-                li = i - blo if local else i
-                x = transformer.block_forward(
-                    x, params["lead_blocks"][li], dense_cfg, window=0)
-            slo, shi = max(blo - n_lead, 0), bhi - n_lead
-            if shi > slo:
-                sub = (params["blocks"] if local
-                       else _tf_slice_blocks(params, slo, shi))
+        blo, bhi, n_lead, slo, shi = _tf_block_range(cfg, lo, hi, n_units)
+        for i in range(blo, min(bhi, n_lead)):
+            dense_cfg = dataclasses.replace(
+                cfg, moe=None, d_ff=cfg.moe.dense_d_ff or cfg.d_ff)
+            li = i - blo if local else i
+            x = transformer.block_forward(
+                x, params["lead_blocks"][li], dense_cfg, window=0)
+        if shi > slo:
+            sub = (params["blocks"] if local
+                   else _tf_slice_blocks(params, slo, shi))
+            window = _static_window(cfg)
+            if window is None:       # per-layer windows ride the scan
+                xs = (sub, jnp.asarray(cfg.windows())[n_lead + slo:n_lead + shi])
+            else:                    # one static window: no traced mask
+                xs = sub
 
-                def body(h, inputs):
-                    _count_trace()
-                    lp, w = inputs
-                    return transformer.block_forward(h, lp, cfg, window=w), None
+            def body(h, inputs):
+                _count_trace()
+                lp, w = inputs if window is None else (inputs, window)
+                return transformer.block_forward(h, lp, cfg, window=w), None
 
-                x, _ = jax.lax.scan(
-                    body, x, (sub, windows[n_lead + slo:n_lead + shi]))
+            x, _ = jax.lax.scan(body, x, xs)
         if hi == L + 2:
             x = transformer.apply_norm(x, params["final_norm"], cfg.norm)
             return transformer.logits_fn(params, cfg, x)
@@ -188,6 +209,8 @@ class SegmentRunner:
     hi: int
     local: bool = False
     stats: ServingStats = dataclasses.field(default_factory=ServingStats)
+    _kernel_layers: dict[int, int] = dataclasses.field(
+        default_factory=dict, init=False, repr=False)
 
     def __post_init__(self) -> None:
         n = self.n_units
@@ -198,6 +221,25 @@ class SegmentRunner:
     @property
     def n_units(self) -> int:
         return len(self.bundle.model_graph())
+
+    def attention_kernel_layers(self, rows: int) -> int:
+        """The attention layers of this segment that run the splash kernel
+        on a ``rows``-row input: each transformer block decides as
+        ``_segment_forward`` calls it (lead blocks at window 0, scanned ones
+        at :func:`_static_window`, both from position 0).  Kept per row
+        count, since the chain asks on every request."""
+        if rows not in self._kernel_layers:
+            count = 0
+            if self.bundle.family == "transformer":
+                cfg = self.bundle.cfg
+                blo, bhi, n_lead, slo, shi = _tf_block_range(
+                    cfg, self.lo, self.hi, self.n_units)
+                count = (max(min(bhi, n_lead) - blo, 0)
+                         * uses_flash_kernel(rows, 0, 0)
+                         + max(shi - slo, 0)
+                         * uses_flash_kernel(rows, _static_window(cfg), 0))
+            self._kernel_layers[rows] = count
+        return self._kernel_layers[rows]
 
     def __call__(self, params: Any, x: jax.Array) -> jax.Array:
         """x: token ids [B,S] if lo==0, else boundary activations [B,S,d].
@@ -286,8 +328,9 @@ class SegmentChain:
     other side.
 
     Each segment runs inside a profiler span ``segment`` (arguments ``j``,
-    ``lo``, ``hi``) and counts into ``stats``, which an owner that rebuilds
-    the chain passes on so its counts outlive the chain.
+    ``lo``, ``hi``) and counts into ``stats`` (its calls, and its attention
+    layers that run the splash kernel at the request's row count), which an
+    owner that rebuilds the chain passes on so its counts outlive the chain.
     """
 
     bundle: ModelBundle
@@ -314,9 +357,12 @@ class SegmentChain:
     def __call__(self, tokens: jax.Array) -> jax.Array:
         x = tokens
         n = len(self.bundle.model_graph())
+        rows = tokens.shape[1]
         for j, seg in enumerate(self.segments):
             with TraceAnnotation("segment", j=j, lo=seg.lo, hi=seg.hi):
                 self.stats.segment_calls += 1
+                self.stats.attention_kernel_layers += \
+                    seg.runner.attention_kernel_layers(rows)
                 x = seg(x)
             if self.transfer_hook is not None and seg.hi < n:
                 x = self.transfer_hook(j, x)

@@ -111,3 +111,63 @@ def test_moe_layer_compiles_to_a_grouped_matmul_for_v5e(one_chip):
         moe.top_k + moe.num_shared))
     flops = compiled.cost_analysis()["flops"]
     assert 0.95 * routed < flops < 1.1 * routed, (flops, routed)
+
+
+@pytest.mark.parametrize("arch", ["stablelm-3b", "deepseek-v2-lite-16b"])
+def test_prefill_attention_compiles_to_the_flash_kernel_for_v5e(
+        one_chip, monkeypatch, arch):
+    """One layer's attention at published widths on a 2,048-row prompt,
+    as the chip runs it (stablelm-3b: 32 heads of 80; DeepSeek-V2-Lite's
+    latent attention: 16 heads of 192 / 128): the splash kernel's custom
+    call, and no KV-block loop of the XLA scan."""
+    import re
+
+    from repro.models.common import KeyGen
+    from repro.models.transformer import _block_params, attn_forward
+
+    cfg = get(arch)
+    rows = 2_048
+    # the program asks JAX's backend, which here is the CPU's
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    p = jax.eval_shape(lambda: _block_params(
+        cfg, KeyGen(jax.random.PRNGKey(0)), jnp.bfloat16)["attn"])
+    x = jax.ShapeDtypeStruct((1, rows, cfg.d_model), jnp.bfloat16,
+                             sharding=one_chip)
+    compiled = jax.jit(lambda x, p: attn_forward(x, p, cfg, window=0)).lower(
+        x, jax.tree_util.tree_map(sds, p)).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and "splash" in hlo
+    assert not re.search(r"\swhile\(", hlo)
+
+
+@pytest.mark.parametrize("arch,layers", [
+    ("stablelm-3b", 2), ("deepseek-v2-lite-16b", 3), ("gemma2-9b", 0)])
+def test_segment_program_runs_the_kernel_where_the_chain_counts_it(
+        one_chip, monkeypatch, arch, layers):
+    """A whole-model segment at the reduced widths on a 1,024-row prompt:
+    the compiled program holds the splash kernel exactly where
+    ``attention_kernel_layers`` counts its layers (gemma2's mixed local and
+    global windows keep the XLA scan)."""
+    from repro.configs import get_bundle
+    from repro.serving import SegmentChain
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    b = get_bundle(arch, reduced=True)
+    params = jax.eval_shape(lambda: b.init(jax.random.PRNGKey(0),
+                                           jnp.bfloat16))
+    seg = SegmentChain(b, params, (0, len(b.model_graph())),
+                       slice_params=False).segments[0]
+    assert seg.runner.attention_kernel_layers(1_024) == layers
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    hlo = seg.runner._program.lower(
+        jax.tree_util.tree_map(sds, seg.params),
+        jax.ShapeDtypeStruct((1, 1_024), jnp.int32, sharding=one_chip),
+    ).compile().as_text()
+    assert ("splash_mha_fwd" in hlo) == (layers > 0)
