@@ -2,7 +2,10 @@
 
 Everything downstream (training step, serving engine, dry-run, orchestrator
 graph extraction) goes through this interface, so adding an architecture means
-writing a config file, not touching the runtime.
+writing a config file, not touching the runtime.  The served split chain
+(``repro.serving.segments``) uses ``model_graph`` and the three ``segment_*``
+functions, which each family defines in its own module; ``prefill`` and
+``decode`` serve the batcher, ``loss`` the training step.
 """
 
 from __future__ import annotations
@@ -105,6 +108,12 @@ class ModelBundle:
     decode: Callable[..., tuple]              # (params, cache, tokens, pos)
     cache_spec: Callable[..., Any]            # (batch, max_len) -> SDS pytree
     model_graph: Callable[[], ModelGraph]
+    # graph units [lo, hi) as one node holds and runs them (x: token ids if
+    # lo == 0, else activations; fp32 logits out if hi ends with the head);
+    # module functions taking cfg first, so a program cache can key on them
+    segment_params: Callable[..., Any]         # (cfg, params, lo, hi) -> view
+    segment_forward: Callable[..., jax.Array]  # (cfg, view, lo, hi, x) -> x
+    segment_kernel_layers: Callable[..., int]  # (cfg, lo, hi, rows) -> int
     supports_long_context: bool = False
 
     def param_specs(self, dtype=jnp.float32) -> Any:
@@ -193,6 +202,9 @@ def _transformer_bundle(arch: str, cfg: transformer.TransformerConfig,
             2.0 * cfg.active_params_per_block, 2.0 * cfg.params_per_block,
             emb_b, 0.0 if cfg.tie_embeddings else emb_b,
             2.0 * cfg.vocab * cfg.d_model),
+        segment_params=transformer.segment_params,
+        segment_forward=transformer.segment_forward,
+        segment_kernel_layers=transformer.segment_kernel_layers,
         supports_long_context=False,
     )
 
@@ -232,6 +244,9 @@ def _mamba2_bundle(arch: str, cfg: mamba2.Mamba2Config) -> ModelBundle:
             2.0 * cfg.params_per_block, 2.0 * cfg.params_per_block,
             emb_b, 0.0 if cfg.tie_embeddings else emb_b,
             2.0 * cfg.vocab * cfg.d_model),
+        segment_params=mamba2.segment_params,
+        segment_forward=mamba2.segment_forward,
+        segment_kernel_layers=lambda cfg, lo, hi, rows: 0,   # no splash kernel
         supports_long_context=True,
     )
 
@@ -334,6 +349,9 @@ def _griffin_bundle(arch: str, cfg: griffin.GriffinConfig) -> ModelBundle:
             arch, cfg.n_layers, cfg.d_model, 2.0 * mean_block, 2.0 * mean_block,
             emb_b, 0.0 if cfg.tie_embeddings else emb_b,
             2.0 * cfg.vocab * cfg.d_model),
+        segment_params=griffin.segment_params,
+        segment_forward=griffin.segment_forward,
+        segment_kernel_layers=lambda cfg, lo, hi, rows: 0,   # no splash kernel
         supports_long_context=True,
     )
 

@@ -190,3 +190,31 @@ def cast_tree(params: Params, dtype) -> Params:
         lambda x: x.astype(dtype) if jnp.issubdtype(x.dtype, jnp.floating) else x,
         params,
     )
+
+
+def unit_blocks(cfg: Any, lo: int, hi: int) -> range:
+    """The blocks among graph units [lo, hi) of a model whose units are
+    [embed, block_0 .. block_{n_layers-1}, lm_head]."""
+    return range(max(lo, 1) - 1, min(hi - 1, cfg.n_layers))
+
+
+def segment_ends(cfg: Any, params: Params, lo: int, hi: int) -> dict:
+    """The embedding, final norm and head that graph units [lo, hi) need;
+    a tied head reads the embedding."""
+    last = hi == cfg.n_layers + 2
+    seg = {"embed": params["embed"]} if lo == 0 or (last and cfg.tie_embeddings) else {}
+    if last:
+        seg["final_norm"] = params["final_norm"]
+        if not cfg.tie_embeddings:
+            seg["head"] = params["head"]
+    return seg
+
+
+def segment_blocks(blocks: Any, n: int) -> Any:
+    """A segment view's blocks (a list, or one tree stacked on axis 0),
+    checked while tracing to be the segment's ``n``: it runs every block
+    it is handed, so another range's view would run the wrong layers."""
+    held = len(blocks) if isinstance(blocks, list) else jax.tree_util.tree_leaves(blocks)[0].shape[0]
+    if held != n:
+        raise ValueError(f"a segment of {n} blocks was handed {held}")
+    return blocks

@@ -28,12 +28,14 @@ from .common import (
     dense_init,
     embed_init,
     norm_params,
+    segment_ends,
     softcap,
+    unit_blocks,
 )
 
 __all__ = ["GriffinConfig", "init_params", "forward_hidden", "decode_step",
            "cache_spec", "init_cache", "rglru", "rglru_reference", "logits_fn",
-           "embed_tokens"]
+           "embed_tokens", "segment_params", "segment_forward"]
 
 NEG_INF = -2.0e38
 _C = 8.0  # RG-LRU decay sharpness constant
@@ -319,6 +321,44 @@ def forward_hidden(params, cfg: GriffinConfig, x, *, remat: bool = True):
 def logits_fn(params, cfg: GriffinConfig, h):
     w = params["embed"].T if cfg.tie_embeddings else params["head"]
     return softcap((h @ w.astype(h.dtype)).astype(jnp.float32), cfg.final_softcap)
+
+
+def segment_params(cfg: GriffinConfig, params: Params, lo: int, hi: int) -> Params:
+    """What graph units [lo, hi) run over: the tree their node holds, with
+    every layer (``groups`` and ``tail`` whole, indexed by layer)."""
+    seg = segment_ends(cfg, params, lo, hi)
+    if unit_blocks(cfg, lo, hi):
+        seg["groups"], seg["tail"] = params["groups"], params["tail"]
+    return seg
+
+
+def segment_forward(cfg: GriffinConfig, params: Params, lo: int, hi: int,
+                    x: jax.Array) -> jax.Array:
+    """Graph units [lo, hi) over their :func:`segment_params` view."""
+    if lo == 0:
+        x = embed_tokens(params, cfg, x)
+    kinds = cfg.layer_kinds()
+    glen = len(cfg.pattern)
+    n_groups = cfg.n_layers // glen
+    for li in unit_blocks(cfg, lo, hi):
+        if li < n_groups * glen:
+            g, i = divmod(li, glen)
+            gp = jax.tree_util.tree_map(lambda a, g=g: a[g], params["groups"])
+            tm, mp = gp[f"t{i}"], gp[f"m{i}"]
+        else:
+            tl = params["tail"][li - n_groups * glen]
+            tm, mp = tl["t"], tl["m"]
+        if kinds[li] == "rec":
+            x = rec_forward(x, tm, cfg)
+        else:
+            x = attn_forward(x, tm, cfg)
+        # round at every layer boundary as a cut would, so that where the
+        # split falls never changes what the layers compute
+        x = jax.lax.optimization_barrier(mlp_forward(x, mp, cfg))
+    if hi == cfg.n_layers + 2:
+        x = apply_norm(x, params["final_norm"], cfg.norm)
+        return logits_fn(params, cfg, x)
+    return x
 
 
 # --------------------------------------------------------------------------- #

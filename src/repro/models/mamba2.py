@@ -18,11 +18,12 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from .common import KeyGen, Params, activation, apply_norm, dense_init, embed_init, norm_params
+from .common import (KeyGen, Params, activation, apply_norm, dense_init, embed_init,
+                     norm_params, segment_blocks, segment_ends, unit_blocks)
 
 __all__ = ["Mamba2Config", "init_params", "forward_hidden", "decode_step",
            "cache_spec", "init_cache", "ssd_chunked", "ssd_reference",
-           "logits_fn", "embed_tokens"]
+           "logits_fn", "embed_tokens", "segment_params", "segment_forward"]
 
 
 @dataclass(frozen=True)
@@ -259,6 +260,33 @@ def forward_hidden(params, cfg: Mamba2Config, x, *, remat: bool = True):
 def logits_fn(params, cfg: Mamba2Config, h):
     w = params["embed"].T if cfg.tie_embeddings else params["head"]
     return (h @ w.astype(h.dtype)).astype(jnp.float32)
+
+
+def segment_params(cfg: Mamba2Config, params: Params, lo: int, hi: int) -> Params:
+    """What graph units [lo, hi) run over: the tree their node holds."""
+    seg = segment_ends(cfg, params, lo, hi)
+    blocks = unit_blocks(cfg, lo, hi)
+    if blocks:
+        seg["blocks"] = jax.tree_util.tree_map(
+            lambda a: a[blocks.start:blocks.stop], params["blocks"])
+    return seg
+
+
+def segment_forward(cfg: Mamba2Config, params: Params, lo: int, hi: int,
+                    x: jax.Array) -> jax.Array:
+    """Graph units [lo, hi) over their :func:`segment_params` view."""
+    if lo == 0:
+        x = embed_tokens(params, cfg, x)
+    blocks = unit_blocks(cfg, lo, hi)
+    if blocks:
+        def body(h, lp):
+            return block_forward(h, lp, cfg), None
+
+        x, _ = jax.lax.scan(body, x, segment_blocks(params["blocks"], len(blocks)))
+    if hi == cfg.n_layers + 2:
+        x = apply_norm(x, params["final_norm"], cfg.norm)
+        return logits_fn(params, cfg, x)
+    return x
 
 
 # --------------------------------------------------------------------------- #

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..distributed.context import constrain
-from .attention import causal_attention
+from .attention import causal_attention, uses_flash_kernel
 from .common import (
     KeyGen,
     Params,
@@ -38,7 +38,10 @@ from .common import (
     dense_init,
     embed_init,
     norm_params,
+    segment_blocks,
+    segment_ends,
     softcap,
+    unit_blocks,
 )
 
 # --------------------------------------------------------------------------- #
@@ -412,20 +415,16 @@ def forward_hidden(
             x = block_forward(x, lp, dense_cfg, window=0, q_offset=q_offset,
                               kv_block=kv_block)
 
-    uniform = len(set(win_np.tolist())) == 1   # static window -> cheaper masks
+    window = _static_window(cfg)   # static window -> cheaper masks
 
     def body(h, inputs):
-        if uniform:
-            lp = inputs
-            w = int(win_np[0])
-        else:
-            lp, w = inputs
+        lp, w = inputs if window is None else (inputs, window)
         return block_forward(h, lp, cfg, window=w, q_offset=q_offset,
                              kv_block=kv_block), None
 
     if remat:
         body = jax.checkpoint(body, policy=jax.checkpoint_policies.nothing_saveable)
-    xs = params["blocks"] if uniform else (
+    xs = params["blocks"] if window is not None else (
         params["blocks"], jnp.asarray(win_np)[n_lead:])
     x, _ = jax.lax.scan(body, x, xs)
     return apply_norm(x, params["final_norm"], cfg.norm)
@@ -435,3 +434,79 @@ def logits_fn(params: Params, cfg: TransformerConfig, h: jax.Array) -> jax.Array
     w = params["embed"].T if cfg.tie_embeddings else params["head"]
     logits = h @ w.astype(h.dtype)
     return softcap(logits.astype(jnp.float32), cfg.final_softcap)
+
+
+# --------------------------------------------------------------------------- #
+# segments: graph units [lo, hi) as one node of a split holds and runs them
+# --------------------------------------------------------------------------- #
+def _static_window(cfg: TransformerConfig) -> int | None:
+    """The attention window every scanned block gets, as a Python int, where
+    the schedule is uniform (so the program knows it while tracing); None
+    where blocks differ and the scan carries each one's window."""
+    w = cfg.windows()
+    return int(w[0]) if len(set(w.tolist())) == 1 else None
+
+
+def _block_range(cfg: TransformerConfig, lo: int, hi: int):
+    """Graph units [lo, hi) as blocks: the count of dense lead blocks, and
+    the indices the segment holds of ``params["lead_blocks"]`` and of the
+    scanned ``params["blocks"]``."""
+    n_lead = cfg.moe.first_dense_layers if cfg.moe else 0
+    blocks = unit_blocks(cfg, lo, hi)
+    return (n_lead, range(blocks.start, min(blocks.stop, n_lead)),
+            range(max(blocks.start - n_lead, 0), blocks.stop - n_lead))
+
+
+def segment_params(cfg: TransformerConfig, params: Params, lo: int, hi: int) -> Params:
+    """What graph units [lo, hi) run over: the tree their node holds."""
+    seg = segment_ends(cfg, params, lo, hi)
+    if lo == 0 and "prefix_proj" in params:
+        seg["prefix_proj"] = params["prefix_proj"]
+    _, lead, scanned = _block_range(cfg, lo, hi)
+    if lead:
+        seg["lead_blocks"] = params["lead_blocks"][lead.start:lead.stop]
+    if scanned:
+        seg["blocks"] = jax.tree_util.tree_map(
+            lambda a: a[scanned.start:scanned.stop], params["blocks"])
+    return seg
+
+
+def segment_forward(cfg: TransformerConfig, params: Params, lo: int, hi: int,
+                    x: jax.Array) -> jax.Array:
+    """Graph units [lo, hi) over their :func:`segment_params` view."""
+    if lo == 0:
+        x = embed_tokens(params, cfg, x)
+    n_lead, lead, scanned = _block_range(cfg, lo, hi)
+    if lead:
+        dense_cfg = dataclasses.replace(
+            cfg, moe=None, d_ff=cfg.moe.dense_d_ff or cfg.d_ff)
+        for lp in segment_blocks(params["lead_blocks"], len(lead)):
+            x = block_forward(x, lp, dense_cfg, window=0)
+    if scanned:
+        sub = segment_blocks(params["blocks"], len(scanned))
+        window = _static_window(cfg)
+        if window is None:       # per-layer windows ride the scan
+            xs = (sub, jnp.asarray(cfg.windows())[
+                n_lead + scanned.start:n_lead + scanned.stop])
+        else:                    # one static window: no traced mask
+            xs = sub
+
+        def body(h, inputs):
+            lp, w = inputs if window is None else (inputs, window)
+            return block_forward(h, lp, cfg, window=w), None
+
+        x, _ = jax.lax.scan(body, x, xs)
+    if hi == cfg.n_layers + 2:
+        x = apply_norm(x, params["final_norm"], cfg.norm)
+        return logits_fn(params, cfg, x)
+    return x
+
+
+def segment_kernel_layers(cfg: TransformerConfig, lo: int, hi: int,
+                          rows: int) -> int:
+    """The attention layers of units [lo, hi) that run the splash kernel on
+    a ``rows``-row input, each as :func:`segment_forward` calls it (lead
+    blocks at window 0, scanned ones at :func:`_static_window`, offset 0)."""
+    _, lead, scanned = _block_range(cfg, lo, hi)
+    return (len(lead) * uses_flash_kernel(rows, 0, 0)
+            + len(scanned) * uses_flash_kernel(rows, _static_window(cfg), 0))

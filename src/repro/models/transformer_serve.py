@@ -22,6 +22,7 @@ from .attention import decode_attention, update_kv_cache
 from .common import Params, apply_norm, apply_rope, softcap
 from .transformer import (
     TransformerConfig,
+    _static_window,
     block_forward,
     dense_ffn,
     embed_tokens,
@@ -124,14 +125,10 @@ def prefill(params: Params, cfg: TransformerConfig, tokens_or_embeds: jax.Array,
                                 dense_cfg, pos)))
             x = block_forward(x, lp, dense_cfg, window=0, kv_block=kv_block)
 
-    uniform = len(set(win_np.tolist())) == 1
+    window = _static_window(cfg)
 
     def body(h, inputs):
-        if uniform:
-            lp = inputs
-            w = int(win_np[0])
-        else:
-            lp, w = inputs
+        lp, w = inputs if window is None else (inputs, window)
         kv = _project_kv(apply_norm(h, lp["ln1"], cfg.norm), lp["attn"], cfg, pos)
         kv = jax.tree_util.tree_map(lambda a: a.astype(cache_dtype), kv)
         h = block_forward(h, lp, cfg, window=w, kv_block=kv_block)
@@ -139,7 +136,7 @@ def prefill(params: Params, cfg: TransformerConfig, tokens_or_embeds: jax.Array,
 
     if remat:
         body = jax.checkpoint(body, policy=jax.checkpoint_policies.nothing_saveable)
-    xs = params["blocks"] if uniform else (
+    xs = params["blocks"] if window is not None else (
         params["blocks"], jnp.asarray(win_np)[n_lead:])
     x, scan_cache = jax.lax.scan(body, x, xs)
     x = apply_norm(x, params["final_norm"], cfg.norm)

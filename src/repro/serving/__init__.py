@@ -4,11 +4,11 @@ from .batching import BatchStats, Request, WaveBatcher
 from .engine import SplitInferenceEngine
 from .profiler import SegmentProfiler
 from .segments import (BoundSegment, SegmentChain, SegmentRunner,
-                       ServingStats, run_chain, split_params)
+                       ServingStats, split_params)
 from .transfer import ActivationTransport, TransferStats
 
 __all__ = ["ActivationTransport", "BatchStats", "BoundSegment", "Request",
            "SegmentChain", "SegmentProfiler", "SegmentRunner",
            "ServingStats", "SplitInferenceEngine", "TransferStats",
            "WaveBatcher",
-           "run_chain", "split_params"]
+           "split_params"]

@@ -21,7 +21,6 @@ from typing import Any
 import jax.numpy as jnp
 
 from ..core.broadcast import PartitionConfig
-from ..core.graph import ModelGraph
 from ..models.api import ModelBundle
 from .segments import SegmentChain, SegmentRunner, ServingStats
 from .transfer import ActivationTransport, TransferStats
@@ -35,42 +34,31 @@ class SplitInferenceEngine:
     params: Any
     transport: ActivationTransport = field(default_factory=ActivationTransport)
     config: PartitionConfig | None = None
-    node_params: dict[int, list] = field(default_factory=dict)
     reconfigurations: int = 0
     chain: SegmentChain | None = None
     # counts of the served path; every chain the engine stages counts here,
     # so they outlive a re-split
     stats: ServingStats = field(default_factory=ServingStats)
 
-    def graph(self) -> ModelGraph:
-        return self.bundle.model_graph()
-
     # -------------------------------------------------------------- config --
     def apply_config(self, cfg: PartitionConfig) -> None:
-        """Stage per-node segment params and activate the new split.
+        """Stage each segment's params (its chain segment holds them) and
+        activate the new split.
 
         The old chain's staged slices are dropped first: at full width a
         second staged copy alongside the full tree does not fit one device.
         """
         self.chain = None
-        self.node_params = {}
         self.chain = SegmentChain(self.bundle, self.params, cfg.boundaries,
                                   transfer_hook=self.transport,
                                   stats=self.stats)
-        staged: dict[int, list] = {}
-        for j, (node, seg) in enumerate(zip(cfg.assignment,
-                                            self.chain.segments)):
-            staged.setdefault(node, []).append((cfg.boundaries[j],
-                                                cfg.boundaries[j + 1],
-                                                seg.params))
-        self.node_params = staged
         if self.config is not None and cfg.version != self.config.version:
             self.reconfigurations += 1
         self.config = cfg
 
     def staged_bytes_per_node(self) -> dict[int, float]:
         """Weight bytes resident per node under the active split (Eq. 4)."""
-        g = self.graph()
+        g = self.bundle.model_graph()
         out: dict[int, float] = {}
         assert self.config is not None
         for j, node in enumerate(self.config.assignment):
@@ -90,7 +78,7 @@ class SplitInferenceEngine:
 
     def infer_monolithic(self, tokens: jnp.ndarray) -> jnp.ndarray:
         """Reference single-node forward (equivalence oracle)."""
-        n = len(self.graph())
+        n = len(self.bundle.model_graph())
         return SegmentRunner(self.bundle, 0, n)(self.params, tokens)
 
     def transfer_stats(self) -> TransferStats:

@@ -4,9 +4,8 @@ The control plane prices the ANALYTIC cost model (``repro.core.cost_model``);
 this module produces the measured coefficients that calibrate it.  For one
 catalog model it drives a :class:`~repro.serving.segments.SegmentChain` —
 the same entrypoint the inference engine uses, so the measured forward
-exercises the real per-architecture kernels (flash attention, ssd_chunk,
-rglru, and int8_transfer when the transport compresses) — and records, per
-segment [lo, hi):
+runs each family's own segment walk (and the int8 transport kernels when
+the transport compresses) — and records, per segment [lo, hi):
 
 * ``step_time_s`` — median wall time of the segment's jitted prefill step
   over ``reps`` runs after ``warmup`` compile/warm runs (block_until_ready);

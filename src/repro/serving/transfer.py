@@ -31,8 +31,9 @@ class TransferStats:
 
 @dataclass
 class ActivationTransport:
-    """transfer_hook for ``segments.run_chain``; each crossing runs inside a
-    profiler span ``transport`` (argument ``boundary``)."""
+    """transfer_hook for :class:`~repro.serving.segments.SegmentChain`; each
+    crossing runs inside a profiler span ``transport`` (argument
+    ``boundary``)."""
 
     compress: bool = False
     interpret: bool = False     # Pallas interpreter; CPU callers opt in

@@ -18,8 +18,8 @@ from repro.models.attention import (
     splash_attention,
     uses_flash_kernel,
 )
+from repro.models.transformer import _static_window
 from repro.serving import SegmentChain
-from repro.serving.segments import _static_window
 
 # (heads, kv heads, qk head size, v head size, softmax scale)
 _DENSE = (4, 4, 80, 80, None)           # stablelm-3b's head size
@@ -121,11 +121,13 @@ def test_causal_attention_keeps_the_xla_scan_off_the_tpu():
 
 
 def _chain(arch, bounds_of):
+    """The chain over zeros of the params' shapes: host arrays, which its
+    segment views slice without running anything."""
     b = get_bundle(arch, reduced=True)
-    params = jax.eval_shape(lambda: b.init(jax.random.PRNGKey(0),
-                                           jnp.float32))
-    return SegmentChain(b, params, bounds_of(len(b.model_graph())),
-                        slice_params=False)
+    params = jax.tree_util.tree_map(
+        lambda a: np.zeros(a.shape, a.dtype),
+        jax.eval_shape(lambda: b.init(jax.random.PRNGKey(0), jnp.float32)))
+    return SegmentChain(b, params, bounds_of(len(b.model_graph())))
 
 
 @pytest.mark.parametrize("arch,bounds_of,layers", [

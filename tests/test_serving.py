@@ -11,9 +11,10 @@ from repro.configs import ALL_ARCHS, get_bundle
 from repro.serving import (
     ActivationTransport,
     Request,
+    SegmentChain,
+    SegmentRunner,
     SplitInferenceEngine,
     WaveBatcher,
-    run_chain,
     split_params,
 )
 from repro.core.broadcast import PartitionConfig
@@ -30,24 +31,26 @@ def _bundle_params(arch):
 from conftest import tier1_subset
 
 
-# tier-1 keeps one representative split==monolith canary; the cross-family
-# sweep (each ~10-18 s of compile) rides the slow marker
+# tier-1 keeps a split==monolith canary of each family's segment walk (the
+# dense transformer, the one with a dense lead block, mamba2 and griffin);
+# the rest of the sweep rides the slow marker
 @pytest.mark.parametrize("arch", tier1_subset(
     ["llama3-8b", "gemma2-9b", "mamba2-1.3b", "recurrentgemma-9b",
      "qwen3-moe-30b-a3b", "deepseek-v2-lite-16b", "musicgen-medium"],
-    keep=("llama3-8b",)))
+    keep=("llama3-8b", "mamba2-1.3b", "recurrentgemma-9b",
+          "deepseek-v2-lite-16b")))
 def test_split_chain_equals_monolith(arch):
     b, params = _bundle_params(arch)
     L = len(b.model_graph())
     toks = jax.random.randint(_KEY, (2, 24), 0, b.cfg.vocab)
-    mono = run_chain(b, params, (0, L), toks)
+    mono = SegmentChain(b, params, (0, L))(toks)
     candidates = [(0, 1, L), (0, L // 2, L), (0, 1, L - 1, L),
                   (0, 2, 3, L - 1, L)]
     for bounds in candidates:
         bounds = tuple(sorted(set(min(max(x, 0), L) for x in bounds)))
         if len(bounds) < 2 or bounds[0] != 0 or bounds[-1] != L:
             continue
-        split = run_chain(b, params, bounds, toks)
+        split = SegmentChain(b, params, bounds)(toks)
         err = float(jnp.max(jnp.abs(mono - split)))
         assert err < 1e-4, (bounds, err)
 
@@ -60,8 +63,8 @@ def test_split_equivalence_random_cuts(cuts):
     L = len(b.model_graph())
     bounds = tuple([0] + sorted(cuts) + [L])
     toks = jax.random.randint(_KEY, (1, 12), 0, b.cfg.vocab)
-    mono = run_chain(b, params, (0, L), toks)
-    split = run_chain(b, params, bounds, toks)
+    mono = SegmentChain(b, params, (0, L))(toks)
+    split = SegmentChain(b, params, bounds)(toks)
     assert float(jnp.max(jnp.abs(mono - split))) < 1e-4
 
 
@@ -205,10 +208,10 @@ def test_transport_compression_accounting():
     L = len(b.model_graph())
     toks = jax.random.randint(_KEY, (2, 16), 0, b.cfg.vocab)
     raw = ActivationTransport(compress=False, interpret=True)
-    run_chain(b, params, (0, 2, L), toks, transfer_hook=raw)
+    SegmentChain(b, params, (0, 2, L), transfer_hook=raw)(toks)
     comp = ActivationTransport(compress=True, interpret=True)
-    out_c = run_chain(b, params, (0, 2, L), toks, transfer_hook=comp)
-    out_r = run_chain(b, params, (0, 2, L), toks, transfer_hook=None)
+    out_c = SegmentChain(b, params, (0, 2, L), transfer_hook=comp)(toks)
+    out_r = SegmentChain(b, params, (0, 2, L))(toks)
     assert comp.stats.compression_ratio > 1.7       # ~2x minus scale overhead
     assert raw.stats.compression_ratio == 1.0
     # int8 transfer costs bounded accuracy loss at the logits
@@ -223,6 +226,20 @@ def test_split_params_cover_and_partition():
     assert "embed" in segs[0]
     assert "final_norm" in segs[-1]
     assert "lead_blocks" in segs[1] or "blocks" in segs[1]
+
+
+def test_part_range_runner_refuses_the_full_tree():
+    """A runner runs every block of the view it is handed, so a part-range
+    runner given more blocks than its segment holds raises while its
+    program traces, where it would otherwise run the wrong layers."""
+    b, params = _bundle_params("stablelm-3b")
+    L = len(b.model_graph())
+    toks = jax.random.randint(_KEY, (1, 8), 0, b.cfg.vocab)
+    runner = SegmentRunner(b, 0, L - 2)
+    with pytest.raises(ValueError, match="blocks"):
+        runner(params, toks)
+    view = split_params(b, params, (0, L - 2, L))[0]
+    assert runner(view, toks).shape == (1, 8, b.cfg.d_model)
 
 
 def test_wave_batcher_completes_all():

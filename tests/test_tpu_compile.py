@@ -153,21 +153,21 @@ def test_segment_program_runs_the_kernel_where_the_chain_counts_it(
     ``attention_kernel_layers`` counts its layers (gemma2's mixed local and
     global windows keep the XLA scan)."""
     from repro.configs import get_bundle
-    from repro.serving import SegmentChain
+    from repro.serving import SegmentRunner
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     b = get_bundle(arch, reduced=True)
+    # a whole-model segment's view is the full tree
     params = jax.eval_shape(lambda: b.init(jax.random.PRNGKey(0),
                                            jnp.bfloat16))
-    seg = SegmentChain(b, params, (0, len(b.model_graph())),
-                       slice_params=False).segments[0]
-    assert seg.runner.attention_kernel_layers(1_024) == layers
+    runner = SegmentRunner(b, 0, len(b.model_graph()))
+    assert runner.attention_kernel_layers(1_024) == layers
 
     def sds(a):
         return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
 
-    hlo = seg.runner._program.lower(
-        jax.tree_util.tree_map(sds, seg.params),
+    hlo = runner._program.lower(
+        jax.tree_util.tree_map(sds, params),
         jax.ShapeDtypeStruct((1, 1_024), jnp.int32, sharding=one_chip),
     ).compile().as_text()
     assert ("splash_mha_fwd" in hlo) == (layers > 0)
