@@ -20,6 +20,7 @@ import numpy as np
 
 from ..core.graph import GraphNode, ModelGraph
 from . import griffin, mamba2, transformer, transformer_serve
+from .common import KernelLayers
 
 __all__ = ["ModelBundle", "bundle_for", "softmax_xent", "chunked_softmax_xent",
            "SHAPES", "ShapeSpec"]
@@ -113,7 +114,7 @@ class ModelBundle:
     # module functions taking cfg first, so a program cache can key on them
     segment_params: Callable[..., Any]         # (cfg, params, lo, hi) -> view
     segment_forward: Callable[..., jax.Array]  # (cfg, view, lo, hi, x) -> x
-    segment_kernel_layers: Callable[..., int]  # (cfg, lo, hi, rows) -> int
+    segment_kernel_layers: Callable[..., KernelLayers]  # (cfg, lo, hi, batch, rows)
     supports_long_context: bool = False
 
     def param_specs(self, dtype=jnp.float32) -> Any:
@@ -246,7 +247,7 @@ def _mamba2_bundle(arch: str, cfg: mamba2.Mamba2Config) -> ModelBundle:
             2.0 * cfg.vocab * cfg.d_model),
         segment_params=mamba2.segment_params,
         segment_forward=mamba2.segment_forward,
-        segment_kernel_layers=lambda cfg, lo, hi, rows: 0,   # no splash kernel
+        segment_kernel_layers=lambda cfg, lo, hi, batch, rows: KernelLayers(),
         supports_long_context=True,
     )
 
@@ -351,7 +352,7 @@ def _griffin_bundle(arch: str, cfg: griffin.GriffinConfig) -> ModelBundle:
             2.0 * cfg.vocab * cfg.d_model),
         segment_params=griffin.segment_params,
         segment_forward=griffin.segment_forward,
-        segment_kernel_layers=lambda cfg, lo, hi, rows: 0,   # no splash kernel
+        segment_kernel_layers=lambda cfg, lo, hi, batch, rows: KernelLayers(),
         supports_long_context=True,
     )
 

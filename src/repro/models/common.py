@@ -4,7 +4,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -208,6 +208,13 @@ def segment_ends(cfg: Any, params: Params, lo: int, hi: int) -> dict:
         if not cfg.tie_embeddings:
             seg["head"] = params["head"]
     return seg
+
+
+class KernelLayers(NamedTuple):
+    """The layers of a segment that run a Pallas kernel on a given input."""
+
+    attention: int = 0      # attention through the splash kernel
+    experts: int = 0        # routed experts through the grouped-matmul kernel
 
 
 def segment_blocks(blocks: Any, n: int) -> Any:

@@ -25,10 +25,12 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm as megablox_gmm
 
-from ..distributed.context import constrain
+from ..distributed.context import constrain, current_mesh
 from .attention import causal_attention, uses_flash_kernel
 from .common import (
+    KernelLayers,
     KeyGen,
     Params,
     YaRN,
@@ -245,6 +247,41 @@ def init_params(cfg: TransformerConfig, key: jax.Array,
 # --------------------------------------------------------------------------- #
 # MoE: token-choice top-k, dropless (every token reaches all k of its experts)
 # --------------------------------------------------------------------------- #
+# The routed experts' grouped matmul on the TPU is megablox's (Pallas, shipped
+# with JAX): tiles of _GMM_TM expert-sorted rows that run on across group
+# boundaries, where XLA's grouped matmul gives every group tiles of its own.
+# Each tile takes the whole contraction and width in one step, so the tiles of
+# one expert keep its weight block resident: of the tilings a v5e sweep timed
+# at DeepSeek-V2-Lite's routed row counts, the fastest (PERF.md).
+_GMM_TM = 128
+
+
+def uses_grouped_kernel(routed_rows: int, k: int, n: int) -> bool:
+    """Whether :func:`moe_ffn` runs its grouped products of ``routed_rows``
+    expert-sorted rows, ``k`` wide into ``n``, through megablox's kernel.
+
+    It does on the TPU, in a program of one device (under an activation mesh
+    the compiler would have to partition the kernel, which it cannot), for
+    whole row tiles and widths that are multiples of 128 lanes; everywhere
+    else (the CPU, meshes, decode's few rows) ``lax.ragged_dot`` runs.
+    """
+    return (jax.default_backend() == "tpu" and current_mesh() is None
+            and routed_rows % _GMM_TM == 0 and k % 128 == 0 and n % 128 == 0)
+
+
+def grouped_matmul(a: jax.Array, w: jax.Array, sizes: jax.Array, *,
+                   interpret: bool = False) -> jax.Array:
+    """Rows ``a`` [M, k], sorted by group, times their group's ``w`` [G, k,
+    n]: rows [sum(sizes[:g]), sum(sizes[:g+1])) through ``w[g]``, in the
+    rows' dtype with float32 accumulation, as ``lax.ragged_dot`` computes
+    it.  megablox's forward kernel, which has no backward pass: training
+    traces under an activation mesh, where ``lax.ragged_dot`` runs
+    (``interpret=True`` runs the kernel on the CPU, for tests)."""
+    k, n = w.shape[1:]
+    return megablox_gmm(a, w.astype(a.dtype), sizes, a.dtype, (_GMM_TM, k, n),
+                        interpret=interpret)
+
+
 def _ffn(x: jax.Array, p: Params, cfg: TransformerConfig, mm) -> jax.Array:
     """An FFN on rows ``x`` [N, d] whose products ``mm(a, w)`` compute."""
     h = activation(mm(x, p["wi"]), cfg.act)
@@ -257,9 +294,11 @@ def moe_ffn(x: jax.Array, p: Params, cfg: TransformerConfig) -> jax.Array:
     """x: [B, S, d] -> [B, S, d].
 
     Each token's top-k (token, expert) pairs are sorted by expert, the routed
-    experts run as one grouped matmul with ragged group sizes, and the
-    outputs are summed back per token by gate weight in float32; no capacity,
-    so no token ever loses an expert.  The shared experts see every token.
+    experts run as one grouped matmul with ragged group sizes a projection
+    (:func:`grouped_matmul` where :func:`uses_grouped_kernel` holds, else
+    ``lax.ragged_dot``), and the outputs are summed back per token by gate
+    weight in float32; no capacity, so no token ever loses an expert.  The
+    shared experts see every token.
     """
     moe = cfg.moe
     b, s, d = x.shape
@@ -278,10 +317,12 @@ def moe_ffn(x: jax.Array, p: Params, cfg: TransformerConfig) -> jax.Array:
         xs = xf[order // k]                                       # [T*k, d]
     with jax.named_scope("moe_experts"):
         # rows [sum(sizes[:e]), sum(sizes[:e+1])) go through expert e: one
-        # grouped matmul a projection, a grouped-matmul kernel on the TPU,
-        # so the work is that of the routed rows only
-        ys = _ffn(xs, p["experts"], cfg, lambda a, w: jax.lax.ragged_dot(
-            a, w.astype(a.dtype), sizes))                         # [T*k, d]
+        # grouped matmul a projection, so the work is that of the routed
+        # rows only
+        mm = (partial(grouped_matmul, sizes=sizes)
+              if uses_grouped_kernel(t * k, d, moe.d_expert) else
+              lambda a, w: jax.lax.ragged_dot(a, w.astype(a.dtype), sizes))
+        ys = _ffn(xs, p["experts"], cfg, mm)                      # [T*k, d]
     with jax.named_scope("moe_combine"):
         back = jnp.zeros_like(order).at[order].set(jnp.arange(t * k))
         pairs = ys[back].reshape(t, k, d).astype(jnp.float32)
@@ -503,10 +544,17 @@ def segment_forward(cfg: TransformerConfig, params: Params, lo: int, hi: int,
 
 
 def segment_kernel_layers(cfg: TransformerConfig, lo: int, hi: int,
-                          rows: int) -> int:
-    """The attention layers of units [lo, hi) that run the splash kernel on
-    a ``rows``-row input, each as :func:`segment_forward` calls it (lead
-    blocks at window 0, scanned ones at :func:`_static_window`, offset 0)."""
+                          batch: int, rows: int) -> KernelLayers:
+    """The layers of units [lo, hi) that run a Pallas kernel on an input of
+    ``batch`` sequences of ``rows`` rows, each as :func:`segment_forward`
+    calls it: attention, per sequence, through the splash kernel (lead
+    blocks at window 0, scanned ones at :func:`_static_window`, offset 0),
+    and the routed experts of the scanned blocks, over the whole batch,
+    through the grouped-matmul kernel."""
     _, lead, scanned = _block_range(cfg, lo, hi)
-    return (len(lead) * uses_flash_kernel(rows, 0, 0)
-            + len(scanned) * uses_flash_kernel(rows, _static_window(cfg), 0))
+    moe = cfg.moe
+    return KernelLayers(
+        attention=(len(lead) * uses_flash_kernel(rows, 0, 0)
+                   + len(scanned) * uses_flash_kernel(rows, _static_window(cfg), 0)),
+        experts=len(scanned) * (moe is not None and uses_grouped_kernel(
+            batch * rows * moe.top_k, cfg.d_model, moe.d_expert)))

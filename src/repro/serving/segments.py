@@ -24,6 +24,7 @@ import jax
 from jax.profiler import TraceAnnotation
 
 from ..models.api import ModelBundle
+from ..models.common import KernelLayers
 
 __all__ = ["BoundSegment", "SegmentChain", "SegmentRunner", "ServingStats",
            "split_params"]
@@ -42,15 +43,18 @@ class ServingStats:
     reused after that, across re-splits too, so a warm server counts none.
     The trace counts into the stats of the runner whose call caused it.
     ``attention_kernel_layers`` counts the attention layers that ran
-    through the splash kernel, decided per segment call from its row count
-    by the predicate the program itself uses
-    (:func:`~repro.models.attention.uses_flash_kernel`).
+    through the splash kernel, and ``expert_kernel_layers`` the layers whose
+    routed experts ran through the grouped-matmul kernel, each decided per
+    segment call from its row count by the predicate the program itself
+    uses (:func:`~repro.models.attention.uses_flash_kernel`,
+    :func:`~repro.models.transformer.uses_grouped_kernel`).
     """
 
     requests: int = 0
     segment_calls: int = 0
     segment_traces: int = 0
     attention_kernel_layers: int = 0
+    expert_kernel_layers: int = 0
 
 
 # the stats that a trace of a segment's program counts into: those of the
@@ -98,7 +102,7 @@ class SegmentRunner:
     hi: int
     stats: ServingStats = dataclasses.field(default_factory=ServingStats)
     n_units: int = dataclasses.field(init=False)
-    _kernel_layers: dict[int, int] = dataclasses.field(
+    _kernel_layers: dict[tuple[int, int], KernelLayers] = dataclasses.field(
         default_factory=dict, init=False, repr=False)
 
     def __post_init__(self) -> None:
@@ -107,14 +111,14 @@ class SegmentRunner:
         self._program = _segment_program(self.bundle.segment_forward,
                                          self.bundle.cfg, self.lo, self.hi)
 
-    def attention_kernel_layers(self, rows: int) -> int:
-        """The attention layers of this segment that run the splash kernel
-        on a ``rows``-row input, as the family counts them.  Kept per row
-        count, since the chain asks on every request."""
-        if rows not in self._kernel_layers:
-            self._kernel_layers[rows] = self.bundle.segment_kernel_layers(
-                self.bundle.cfg, self.lo, self.hi, rows)
-        return self._kernel_layers[rows]
+    def kernel_layers(self, batch: int, rows: int) -> KernelLayers:
+        """The layers of this segment that run a Pallas kernel on an input
+        of ``batch`` sequences of ``rows`` rows, as the family counts them.
+        Kept per shape, since the chain asks on every request."""
+        if (batch, rows) not in self._kernel_layers:
+            self._kernel_layers[batch, rows] = self.bundle.segment_kernel_layers(
+                self.bundle.cfg, self.lo, self.hi, batch, rows)
+        return self._kernel_layers[batch, rows]
 
     def __call__(self, params: Any, x: jax.Array) -> jax.Array:
         """x: token ids [B,S] if lo==0, else boundary activations [B,S,d].
@@ -171,8 +175,8 @@ class SegmentChain:
     other side.
 
     Each segment runs inside a profiler span ``segment`` (arguments ``j``,
-    ``lo``, ``hi``) and counts into ``stats`` (its calls, and its attention
-    layers that run the splash kernel at the request's row count), which an
+    ``lo``, ``hi``) and counts into ``stats`` (its calls, and its layers
+    that run a kernel at the request's row count), which an
     owner that rebuilds the chain passes on so its counts outlive the chain.
     """
 
@@ -194,12 +198,13 @@ class SegmentChain:
 
     def __call__(self, tokens: jax.Array) -> jax.Array:
         x = tokens
-        rows = tokens.shape[1]
+        batch, rows = tokens.shape[:2]
         for j, seg in enumerate(self.segments):
             with TraceAnnotation("segment", j=j, lo=seg.lo, hi=seg.hi):
                 self.stats.segment_calls += 1
-                self.stats.attention_kernel_layers += \
-                    seg.runner.attention_kernel_layers(rows)
+                layers = seg.runner.kernel_layers(batch, rows)
+                self.stats.attention_kernel_layers += layers.attention
+                self.stats.expert_kernel_layers += layers.experts
                 x = seg(x)
             if self.transfer_hook is not None and seg.hi < seg.runner.n_units:
                 x = self.transfer_hook(j, x)

@@ -142,9 +142,9 @@ def test_kernel_layer_count_per_segment(monkeypatch, arch, bounds_of, layers):
     the kernel runs, a 64-row one none."""
     chain = _chain(arch, bounds_of)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert [s.runner.attention_kernel_layers(2048)
+    assert [s.runner.kernel_layers(1, 2048).attention
             for s in chain.segments] == layers
-    assert [s.runner.attention_kernel_layers(64)
+    assert [s.runner.kernel_layers(1, 64).attention
             for s in chain.segments] == [0] * len(layers)
     # the chain counts each request through its runners (programs stubbed:
     # nothing may run the kernel on the CPU)

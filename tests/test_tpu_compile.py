@@ -84,15 +84,14 @@ def test_resident_price_compiles_for_v5e(one_chip):
     assert compiled.memory_analysis() is not None
 
 
-def test_moe_layer_compiles_to_a_grouped_matmul_for_v5e(one_chip):
-    """DeepSeek-V2-Lite's expert layer at its published widths on a
-    2,048-row prompt: the routed experts compile to the grouped-matmul
-    kernel, and the compiled FLOPs are those of the routed rows (6 of 64
-    experts a token), not of a dense product over all 64."""
+def _moe_layer_hlo(one_chip, tokens, arch="deepseek-v2-lite-16b"):
+    """A model's expert layer at its published widths on a ``tokens``-row
+    prompt, compiled for the chip: its optimized HLO and FLOPs, and the
+    FLOPs of its routed rows (DeepSeek-V2-Lite: 6 of 64 experts a token)."""
     from repro.models.transformer import moe_ffn
 
-    cfg = get("deepseek-v2-lite-16b")
-    moe, d, t = cfg.moe, cfg.d_model, 2_048
+    cfg = get(arch)
+    moe, d = cfg.moe, cfg.d_model
 
     def sds(shape):
         return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
@@ -103,13 +102,57 @@ def test_moe_layer_compiles_to_a_grouped_matmul_for_v5e(one_chip):
 
     p = {"router": sds((d, moe.num_experts)),
          "experts": ffn(moe.d_expert, moe.num_experts),
-         "shared": ffn(moe.d_expert * moe.num_shared)}
-    compiled = jax.jit(moe_ffn, static_argnums=2).lower(
-        sds((1, t, d)), p, cfg).compile()
-    assert "ragged-dot" in compiled.as_text()
-    routed = 2 * t * d * (moe.num_experts + 3 * moe.d_expert * (
+         **({"shared": ffn(moe.d_expert * moe.num_shared)}
+            if moe.num_shared else {})}
+    # a program of its own: traced with the backend the test sets
+    compiled = jax.jit(lambda x, p: moe_ffn(x, p, cfg)).lower(
+        sds((1, tokens, d)), p).compile()
+    routed = 2 * tokens * d * (moe.num_experts + 3 * moe.d_expert * (
         moe.top_k + moe.num_shared))
-    flops = compiled.cost_analysis()["flops"]
+    return compiled.as_text(), compiled.cost_analysis()["flops"], routed
+
+
+def _holds_gmm(hlo):
+    import re
+
+    return re.search(r"%gmm[.\d]* = .*tpu_custom_call", hlo) is not None
+
+
+def test_moe_layer_compiles_to_a_grouped_matmul_for_v5e(one_chip, monkeypatch):
+    """On a 2,048-row prompt the routed experts compile to megablox's
+    grouped-matmul kernel, and the compiled FLOPs are those of the routed
+    rows (6 of 64 experts a token), not of a dense product over all 64."""
+    # the program asks JAX's backend, which here is the CPU's
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    hlo, flops, routed = _moe_layer_hlo(one_chip, 2_048)
+    assert _holds_gmm(hlo) and "ragged-dot" not in hlo
+    assert 0.95 * routed < flops < 1.1 * routed, (flops, routed)
+
+
+def test_qwen3_moe_layer_compiles_to_a_grouped_matmul_for_v5e(
+        one_chip, monkeypatch):
+    """A shape the tiling was not timed at (Qwen3-MoE: 128 experts of
+    2,048 -> 768, top-8) compiles with its whole contraction and width in
+    one kernel step, on 512 tokens (4,096 routed rows)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    hlo, flops, routed = _moe_layer_hlo(one_chip, 512, "qwen3-moe-30b-a3b")
+    assert _holds_gmm(hlo) and "ragged-dot" not in hlo
+    assert 0.95 * routed < flops < 1.1 * routed, (flops, routed)
+
+
+@pytest.mark.parametrize("tokens,kernel", [(1_024, True), (100, False)])
+def test_moe_layer_takes_the_kernel_where_the_predicate_holds(
+        one_chip, monkeypatch, tokens, kernel):
+    """6,144 routed rows run the three products on the kernel and leave no
+    XLA grouped matmul; 600, not whole row tiles, keep ``ragged-dot``.
+    Either way the FLOPs are the routed rows'."""
+    from repro.models.transformer import uses_grouped_kernel
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert uses_grouped_kernel(6 * tokens, 2_048, 1_408) is kernel
+    hlo, flops, routed = _moe_layer_hlo(one_chip, tokens)
+    assert _holds_gmm(hlo) is kernel
+    assert ("ragged-dot" in hlo) is not kernel
     assert 0.95 * routed < flops < 1.1 * routed, (flops, routed)
 
 
@@ -161,7 +204,7 @@ def test_segment_program_runs_the_kernel_where_the_chain_counts_it(
     params = jax.eval_shape(lambda: b.init(jax.random.PRNGKey(0),
                                            jnp.bfloat16))
     runner = SegmentRunner(b, 0, len(b.model_graph()))
-    assert runner.attention_kernel_layers(1_024) == layers
+    assert runner.kernel_layers(1, 1_024).attention == layers
 
     def sds(a):
         return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
@@ -171,3 +214,38 @@ def test_segment_program_runs_the_kernel_where_the_chain_counts_it(
         jax.ShapeDtypeStruct((1, 1_024), jnp.int32, sharding=one_chip),
     ).compile().as_text()
     assert ("splash_mha_fwd" in hlo) == (layers > 0)
+
+
+@pytest.mark.parametrize("rows,experts", [(1_024, 2), (160, 0)])
+def test_segment_program_runs_the_expert_kernel_where_the_chain_counts_it(
+        one_chip, monkeypatch, rows, experts):
+    """The reduced DeepSeek-V2-Lite at 128-lane widths (two MoE layers
+    behind its dense lead block, 8 experts top-2), as a whole-model segment:
+    the compiled program holds the grouped-matmul kernel exactly where
+    ``expert_kernel_layers`` counts its layers (2,048 routed rows), and the
+    XLA grouped matmul where it counts none (320, not whole row tiles)."""
+    import dataclasses
+
+    from repro.configs import get_reduced
+    from repro.models.api import bundle_for
+    from repro.serving import SegmentRunner
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_reduced("deepseek-v2-lite-16b")
+    cfg = dataclasses.replace(cfg, d_model=128, moe=dataclasses.replace(
+        cfg.moe, d_expert=128))
+    b = bundle_for("deepseek-v2-lite-16b", cfg)
+    params = jax.eval_shape(lambda: b.init(jax.random.PRNGKey(0),
+                                           jnp.bfloat16))
+    runner = SegmentRunner(b, 0, len(b.model_graph()))
+    assert runner.kernel_layers(1, rows).experts == experts
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    hlo = runner._program.lower(
+        jax.tree_util.tree_map(sds, params),
+        jax.ShapeDtypeStruct((1, rows), jnp.int32, sharding=one_chip),
+    ).compile().as_text()
+    assert _holds_gmm(hlo) is (experts > 0)
+    assert ("ragged-dot" in hlo) is (experts == 0)
